@@ -38,6 +38,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.dirname(__file__))
 
 from repro.kernels import ops, ref  # noqa: E402
+from repro.launch.mesh import peaks_for  # noqa: E402
 import roofline  # noqa: E402
 
 # gate shapes: small enough for interpret mode on a CPU runner, big
@@ -177,12 +178,13 @@ def gate(out_dir: str, baseline_dir: Optional[str] = None,
     rows = _variant_rows("ref", reps=reps) \
         + _variant_rows("interpret", reps=reps)
 
+    peaks = peaks_for()
     artifact = {
         "schema": "stretto-kernels-bench-v1",
         "ts": time.strftime("%Y%m%dT%H%M%SZ", time.gmtime()),
         "sha": _git_sha(),
         "backend_device": jax.default_backend(),
-        "peaks": roofline.PEAKS.name,
+        "peaks": peaks.name,
         "shapes": {"B": GATE_B, "S": GATE_S, "KV": GATE_KV, "G": GATE_G,
                    "dk": GATE_DK, "Lq": GATE_LQ},
         "lowering": checks,
@@ -196,9 +198,9 @@ def gate(out_dir: str, baseline_dir: Optional[str] = None,
         failed |= not c["ok"]
 
     print(f"[gate] roofline priced against peak set "
-          f"{roofline.PEAKS.name!r} "
-          f"({roofline.PEAKS.flops / 1e9:.0f} GFLOP/s, "
-          f"{roofline.PEAKS.hbm_bw / 1e9:.0f} GB/s)")
+          f"{peaks.name!r} "
+          f"({peaks.flops / 1e9:.0f} GFLOP/s, "
+          f"{peaks.hbm_bw / 1e9:.0f} GB/s)")
     for r in rows:
         print(f"[perf] {r['name']}: {r['wall_us_per_tuple']:.1f} us/tuple "
               f"(roofline bound {r['roofline_us_per_tuple']:.1f})")

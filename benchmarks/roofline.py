@@ -10,14 +10,7 @@ from typing import Dict, List
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.launch.mesh import resolve_peaks  # noqa: E402
-
-# The peak set the kernels perf gate measures against: the shared
-# CI-CPU defaults from launch/mesh.py (the single source of hardware
-# peak numbers), with the STRETTO_ROOFLINE_GFLOPS / _BW_GBS env
-# overrides applied — a TPU run exports those to gate against real HBM
-# bandwidth. PEAKS.name records which set priced the report.
-PEAKS = resolve_peaks()
+from repro.launch.mesh import peaks_for  # noqa: E402
 
 
 def decode_bound_s(B: int, S: int, KV: int, G: int, dk: int, dv: int,
@@ -31,18 +24,21 @@ def decode_bound_s(B: int, S: int, KV: int, G: int, dk: int, dv: int,
     multi-token path wins over n_q sequential dispatches, and why int8
     (kv_bytes_per_elem=1 + per-token scale_bytes) halves-plus the memory
     time. FLOPs scale with n_q; bytes for q/out are negligible next to
-    the cache stream but included.
+    the cache stream but included. Priced against the peaks of the
+    device the process runs on (`launch.mesh.peaks_for`, which raises
+    for a device it does not list).
     """
+    peaks = peaks_for()
     kv_bytes = B * S * KV * (dk + dv) * kv_bytes_per_elem
     kv_bytes += B * S * KV * 2 * scale_bytes          # k_scale + v_scale
     qo_bytes = B * n_q * KV * G * (dk + dv) * 4
     flops = 2.0 * B * n_q * KV * G * S * (dk + dv)
-    mem_s = (kv_bytes + qo_bytes) / PEAKS.hbm_bw
-    compute_s = flops / PEAKS.flops
+    mem_s = (kv_bytes + qo_bytes) / peaks.hbm_bw
+    compute_s = flops / peaks.flops
     return {"mem_s": mem_s, "compute_s": compute_s,
             "bound_s": max(mem_s, compute_s),
             "dominant": "memory" if mem_s >= compute_s else "compute",
-            "peaks": PEAKS.name}
+            "peaks": peaks.name}
 
 
 def load(out_dir: str = "results/dryrun_sp") -> List[Dict]:

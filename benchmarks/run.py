@@ -50,6 +50,8 @@ def main() -> None:
     args = ap.parse_args()
 
     import repro
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (exp1_accuracy_runtime as E1,
                             exp2_kv_cache as E2, exp3_global_local as E3,
                             kernels_bench, roofline)

@@ -34,6 +34,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import repro
 from repro.data.synthetic import make_dataset
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def single_engine_config() -> "repro.SessionConfig":
@@ -150,6 +151,7 @@ def main():
                     help="serve the fast tier from a loopback worker "
                          "subprocess, then SIGKILL it mid-run")
     args = ap.parse_args()
+    enable_compile_cache()
     ds = make_dataset("quickstart", 200, seed=3)
     if args.remote:
         run_remote(ds)

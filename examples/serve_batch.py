@@ -21,11 +21,13 @@ import numpy as np
 import repro
 from repro.cache.store import Profile
 from repro.data.synthetic import make_dataset
+from repro.launch.compile_cache import enable_compile_cache
 
 RATIOS = (0.0, 0.5, 0.8)
 
 
 def main():
+    enable_compile_cache()
     ds = make_dataset("serve", 200, seed=9)
     config = repro.SessionConfig(
         memory_budget_bytes=5e8,

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.data.pipeline import lm_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.training.loop import LoopConfig, run_training
 from repro.training.optimizer import adamw_init
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     print(f"[train_lm] {cfg.name}: {cfg.n_params / 1e6:.2f}M params")

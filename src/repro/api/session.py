@@ -118,9 +118,9 @@ class EngineSpec:
                          EXPLAIN ANALYZE; None: STRETTO_ASYNC_H2D,
                          default on). Never changes results.
       device           — pin this engine on one device: an index into
-                         jax.devices() (wrapped modulo the device count,
-                         so specs stay valid on smaller hosts). Params
-                         are placed there once and every flush computes
+                         jax.devices(); an index past the device count
+                         raises. Params are placed there once and every
+                         flush computes
                          there. None: jax's default device. A "mesh"
                          session dispatcher overrides this per corpus
                          shard with its own mesh placement.
@@ -546,7 +546,11 @@ class Session:
             if spec.device is not None:
                 import jax
                 devs = jax.devices()
-                eng.default_device = devs[spec.device % len(devs)]
+                if not 0 <= spec.device < len(devs):
+                    raise ValueError(
+                        f"engine {spec.name!r}: device={spec.device} does "
+                        f"not exist ({len(devs)} device(s) visible)")
+                eng.default_device = devs[spec.device]
             for name in spec.models:
                 mcfg = planted_config(name)
                 eng.register_model(

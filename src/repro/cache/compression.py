@@ -88,6 +88,27 @@ def _cache_keys(cfg: ModelConfig) -> Tuple[str, ...]:
     return ()
 
 
+def _ea_layers(k, mu, sig2, backend: str):
+    """Expected-Attention log-scores for every layer of one item:
+    k (L, 1, S, KV, d), mu/sig2 (L, KV, G, d) -> (L, 1, S, KV)."""
+    return jax.vmap(lambda kl, mul, sl: KOPS.expected_attention_scores(
+        kl, mul, sl, backend=backend))(k, mu, sig2)
+
+
+# An eager Pallas call is traced and compiled anew every time, which in
+# the offline phase means once per item and rung; jitted, the kernel
+# compiles once per shape. The jnp oracle stays eager: jitting it could
+# fuse and reorder its float math, and with it which positions are kept.
+_ea_layers_jit = jax.jit(_ea_layers, static_argnames="backend")
+
+
+def _layer_scores(k, mu, sig2):
+    backend = KOPS.resolve_backend(None)
+    if KOPS.runs_ref(backend):
+        return _ea_layers(k, mu, sig2, "ref")
+    return _ea_layers_jit(k, mu, sig2, backend=backend)
+
+
 def score_positions(cfg: ModelConfig, cache: Dict[str, Any],
                     stats: QueryStats, length: int) -> jax.Array:
     """Per-layer keep-scores for one item. cache leaves: (L, 1, S, ...).
@@ -95,17 +116,13 @@ def score_positions(cfg: ModelConfig, cache: Dict[str, Any],
     if cfg.attn_kind in ("gqa", "hymba"):
         k = cache["k"]                                  # (L, 1, S, KV, dk)
         Ln, _, S, KV, dk = k.shape
-        scores = jax.vmap(
-            lambda kl, mul, sl: KOPS.expected_attention_scores(kl, mul, sl)
-        )(k, stats.mu, stats.sig2)                      # (L, 1, S, KV)
+        scores = _layer_scores(k, stats.mu, stats.sig2)  # (L, 1, S, KV)
         scores = scores[:, 0].mean(-1)                  # (L, S)
     else:  # mla: score latent rows [c_kv ; k_rope]
         lat = jnp.concatenate([cache["c_kv"], cache["k_rope"]], axis=-1)
         Ln, _, S, r = lat.shape
         lat4 = lat.reshape(Ln, 1, S, 1, r)              # (L, 1, S, KV=1, r)
-        scores = jax.vmap(
-            lambda kl, mul, sl: KOPS.expected_attention_scores(kl, mul, sl)
-        )(lat4, stats.mu, stats.sig2)
+        scores = _layer_scores(lat4, stats.mu, stats.sig2)
         scores = scores[:, 0, :, 0]                     # (L, S)
     pos = jnp.arange(scores.shape[-1])[None, :]
     return jnp.where(pos < length, scores, -jnp.inf)

@@ -75,7 +75,12 @@ class CacheStore:
         path = self._path(profile, item_id)
         os.makedirs(os.path.dirname(path), exist_ok=True)
         arrs = {k: np.asarray(v) for k, v in arrays.items()}
-        np.savez(path, __length__=np.int32(length), **arrs)
+        # .npz keeps only numpy's own dtypes: a bfloat16 array comes back
+        # as raw 2-byte voids, so every array's dtype is stored beside it
+        # and restored on load
+        dtypes = json.dumps({k: a.dtype.name for k, a in arrs.items()})
+        np.savez(path, __length__=np.int32(length),
+                 __dtypes__=np.asarray(dtypes), **arrs)
         self._mem[(profile.tag, item_id)] = {
             "__length__": np.int32(length), **arrs}
         nbytes = sum(a.nbytes for a in arrs.values())
@@ -126,7 +131,11 @@ class CacheStore:
         key = (profile.tag, item_id)
         if key not in self._mem:
             with np.load(self._path(profile, item_id)) as z:
-                self._mem[key] = {k: z[k] for k in z.files}
+                shard = {k: z[k] for k in z.files}
+            dtypes = json.loads(str(shard.pop("__dtypes__", "{}")))
+            for k, name in dtypes.items():
+                shard[k] = shard[k].view(jnp.dtype(name))
+            self._mem[key] = shard
         return self._mem[key]
 
     def has(self, profile: Profile, item_id: int) -> bool:

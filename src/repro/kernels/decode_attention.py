@@ -3,7 +3,7 @@
 The hot loop of Stretto's KV-cache-enabled operators: query tokens per
 item attend to a precomputed, possibly compressed, right-padded cache.
 
-Two entry points share the online-softmax machinery:
+Two entry points share one kernel:
 
   decode_attention        one query token per item (classic flash-decode)
   decode_query_attention  Lq query tokens per item in ONE dispatch — the
@@ -17,17 +17,23 @@ Two entry points share the online-softmax machinery:
   lengths  (B,) int32        valid prefix per item (compressed lengths)
   window   int or traced int32 scalar; GLOBAL = full attention
 
-`window` is carried as a (1,) int32 *input* (not a static closure
-constant): the model's per-layer window is data in the layer scan
-(gemma3's local:global pattern), so the kernel must accept a traced
-value without retracing per layer.
+`lengths` and `window` are scalar-prefetch operands: they land in SMEM
+before the grid runs, and the kernel reads one scalar per item. `window`
+is data, not a static closure constant, because the model's per-layer
+window is carried through the layer scan (gemma3's local:global pattern)
+and the kernel must accept a traced value without retracing per layer.
 
-Grid (B, KV, S/block_s): the KV-length axis iterates innermost and
+Grid (B, S/block_s): the KV-length axis iterates innermost and
 sequentially on TPU, so the online-softmax state (m, l, acc) lives in VMEM
 scratch across iterations — the TPU-idiomatic analogue of FlashDecoding's
-split-K scheme. K/V tiles stream HBM->VMEM via BlockSpec; the (G, dk) x
-(dk, block_s) score matmul and the (G, block_s) x (block_s, dv) accumulate
-run on the MXU with dk, dv in {64, 128, 256+} and block_s a multiple of 128.
+split-K scheme. Each step streams one (block_s, KV, dk) K tile and its V
+tile HBM->VMEM with every KV head in it: the minor two block dimensions
+are then the array's own (KV, dk), which is what the TPU's tiling rule
+accepts for any head count and head width, with no relayout of the cache.
+The kernel loops over the KV heads in VMEM; per head, the (Lq*G, dk) x
+(dk, block_s) score matmul and the (Lq*G, block_s) x (block_s, dv)
+accumulate run on the MXU. Queries arrive as (KV, Lq*G, dk) rows so that
+no reshape happens inside the kernel.
 
 Per-item `lengths` masking makes padded batches exact — this is what lets
 the serving engine batch caches of different compressed lengths. int8
@@ -49,143 +55,18 @@ GLOBAL = 1 << 30
 
 def _window_arg(window) -> jax.Array:
     """Normalize the window kwarg (python int or traced scalar) to the
-    (1,) int32 kernel input."""
+    (1,) int32 scalar-prefetch operand."""
     return jnp.asarray(window, jnp.int32).reshape(1)
 
 
-# ---------------------------------------------------------------------------
-# single-query flash-decode
-# ---------------------------------------------------------------------------
-
-def _decode_kernel(len_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, block_s: int, n_s: int,
-                   scale: float):
-    s_idx = pl.program_id(2)
-
-    @pl.when(s_idx == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, dk)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (bs, dk)
-    v = v_ref[0, :, 0].astype(jnp.float32)               # (bs, dv)
-    _decode_core(len_ref, win_ref, q, k, v, o_ref, m_ref, l_ref, acc_ref,
-                 block_s=block_s, n_s=n_s, s_idx=s_idx)
-
-
-def _decode_kernel_int8(len_ref, win_ref, q_ref, k_ref, v_ref, ks_ref,
-                        vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                        block_s: int, n_s: int, scale: float):
-    """int8 KV variant: dequantization happens in-register after the VMEM
-    load, so HBM traffic is 1 byte/element + per-token scales (the
-    beyond-paper optimization measured in EXPERIMENTS §Perf)."""
-    s_idx = pl.program_id(2)
-
-    @pl.when(s_idx == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32) * scale
-    ks = ks_ref[0, :, 0].astype(jnp.float32)             # (bs,)
-    vs = vs_ref[0, :, 0].astype(jnp.float32)
-    k = k_ref[0, :, 0].astype(jnp.float32) * ks[:, None]
-    v = v_ref[0, :, 0].astype(jnp.float32) * vs[:, None]
-    _decode_core(len_ref, win_ref, q, k, v, o_ref, m_ref, l_ref, acc_ref,
-                 block_s=block_s, n_s=n_s, s_idx=s_idx)
-
-
-def _decode_core(len_ref, win_ref, q, k, v, o_ref, m_ref, l_ref, acc_ref, *,
-                 block_s: int, n_s: int, s_idx):
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (G, bs)
-    length = len_ref[0]
-    window = win_ref[0]
-    pos = s_idx * block_s + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_s), 1)
-    mask = (pos < length) & ((length - 1 - pos) < window)
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_ref[...]                                   # (G, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)                                # (G, bs)
-    alpha = jnp.exp(m_prev - m_new)                       # (G, 1)
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)               # (G, dv)
-    m_ref[...] = m_new
-
-    @pl.when(s_idx == n_s - 1)
-    def _finish():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
-
-
-def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                     lengths: jax.Array, *, window=GLOBAL,
-                     block_s: int = 128, interpret: bool = False,
-                     k_scale: jax.Array = None, v_scale: jax.Array = None
-                     ) -> jax.Array:
-    """Flash-decode. Returns (B, KV, G, dv).
-
-    With k_scale/v_scale (B, S, KV) given, k_cache/v_cache are int8 and are
-    dequantized in-register (HBM streams 1 B/elem)."""
-    B, KV, G, dk = q.shape
-    _, S, _, dv = v_cache.shape
-    block_s = min(block_s, S)
-    if S % block_s:
-        raise ValueError(f"S={S} must be a multiple of block_s={block_s}")
-    n_s = S // block_s
-    scale = dk ** -0.5
-    quant = k_scale is not None
-
-    in_specs = [
-        pl.BlockSpec((1,), lambda b, h, s: (b,)),
-        pl.BlockSpec((1,), lambda b, h, s: (0,)),
-        pl.BlockSpec((1, 1, G, dk), lambda b, h, s: (b, h, 0, 0)),
-        pl.BlockSpec((1, block_s, 1, dk), lambda b, h, s: (b, s, h, 0)),
-        pl.BlockSpec((1, block_s, 1, dv), lambda b, h, s: (b, s, h, 0)),
-    ]
-    args = [lengths, _window_arg(window), q, k_cache, v_cache]
+def _query_kernel(len_ref, win_ref, q_ref, k_ref, v_ref, *rest,
+                  block_s: int, n_s: int, n_q: int, G: int, scale: float,
+                  quant: bool):
     if quant:
-        kern = functools.partial(_decode_kernel_int8, block_s=block_s,
-                                 n_s=n_s, scale=scale)
-        in_specs += [
-            pl.BlockSpec((1, block_s, 1), lambda b, h, s: (b, s, h)),
-            pl.BlockSpec((1, block_s, 1), lambda b, h, s: (b, s, h)),
-        ]
-        args += [k_scale, v_scale]
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
-        kern = functools.partial(_decode_kernel, block_s=block_s, n_s=n_s,
-                                 scale=scale)
-    return pl.pallas_call(
-        kern,
-        grid=(B, KV, n_s),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, dv), lambda b, h, s: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, dv), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
-
-
-# ---------------------------------------------------------------------------
-# fused multi-token query decode
-# ---------------------------------------------------------------------------
-
-def _query_kernel(len_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, block_s: int, n_s: int,
-                  n_q: int, scale: float):
-    s_idx = pl.program_id(2)
+        o_ref, m_ref, l_ref, acc_ref = rest
+    s_idx = pl.program_id(1)
 
     @pl.when(s_idx == 0)
     def _init():
@@ -193,67 +74,44 @@ def _query_kernel(len_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0].astype(jnp.float32) * scale       # (Lq, G, dk)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (bs, dk)
-    v = v_ref[0, :, 0].astype(jnp.float32)               # (bs, dv)
-    _query_core(len_ref, win_ref, q, k, v, o_ref, m_ref, l_ref, acc_ref,
-                block_s=block_s, n_s=n_s, n_q=n_q, s_idx=s_idx)
-
-
-def _query_kernel_int8(len_ref, win_ref, q_ref, k_ref, v_ref, ks_ref,
-                       vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                       block_s: int, n_s: int, n_q: int, scale: float):
-    s_idx = pl.program_id(2)
-
-    @pl.when(s_idx == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, :, 0].astype(jnp.float32) * scale
-    ks = ks_ref[0, :, 0].astype(jnp.float32)
-    vs = vs_ref[0, :, 0].astype(jnp.float32)
-    k = k_ref[0, :, 0].astype(jnp.float32) * ks[:, None]
-    v = v_ref[0, :, 0].astype(jnp.float32) * vs[:, None]
-    _query_core(len_ref, win_ref, q, k, v, o_ref, m_ref, l_ref, acc_ref,
-                block_s=block_s, n_s=n_s, n_q=n_q, s_idx=s_idx)
-
-
-def _query_core(len_ref, win_ref, q, k, v, o_ref, m_ref, l_ref, acc_ref, *,
-                block_s: int, n_s: int, n_q: int, s_idx):
-    lq, G, dk = q.shape
-    q2 = q.reshape(lq * G, dk)
-    s = jax.lax.dot_general(q2, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s.reshape(lq, G, block_s)                         # (Lq, G, bs)
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
     window = win_ref[0]
-    # query i sits at absolute position length - n_q + i; causal masking
-    # against the cache positions keeps the fused pass equivalent to the
-    # sequential per-token scan (token i never sees tokens > i)
+    KV, rows, _ = q_ref.shape
+    # row r holds query token r // G, at absolute position
+    # length - n_q + r // G; causal masking against the cache positions
+    # keeps the fused pass equivalent to the sequential per-token scan
+    # (token i never sees tokens > i)
     k_pos = s_idx * block_s + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, block_s), 2)
+        jnp.int32, (1, block_s), 1)
     q_pos = length - n_q + jax.lax.broadcasted_iota(
-        jnp.int32, (lq, 1, 1), 0)
+        jnp.int32, (rows, 1), 0) // G
     mask = (k_pos <= q_pos) & ((q_pos - k_pos) < window)
-    s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]                                   # (Lq, G, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p.reshape(lq * G, block_s), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).reshape(lq, G, -1)
-    acc_ref[...] = acc_ref[...] * alpha + pv
-    m_ref[...] = m_new
+    for h in range(KV):
+        q = q_ref[h].astype(jnp.float32) * scale          # (rows, dk)
+        k = k_ref[:, h, :].astype(jnp.float32)            # (bs, dk)
+        v = v_ref[:, h, :].astype(jnp.float32)            # (bs, dv)
+        if quant:
+            k = k * ks_ref[:, h:h + 1].astype(jnp.float32)
+            v = v * vs_ref[:, h:h + 1].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(mask, s, NEG_INF)                   # (rows, bs)
+
+        m_prev = m_ref[h]                                 # (rows, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[h] = l_ref[h] * alpha + p.sum(axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)           # (rows, dv)
+        m_ref[h] = m_new
 
     @pl.when(s_idx == n_s - 1)
     def _finish():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0] = out.astype(o_ref.dtype)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 def decode_query_attention(q: jax.Array, k_cache: jax.Array,
@@ -276,39 +134,54 @@ def decode_query_attention(q: jax.Array, k_cache: jax.Array,
     if S % block_s:
         raise ValueError(f"S={S} must be a multiple of block_s={block_s}")
     n_s = S // block_s
-    scale = dk ** -0.5
+    rows = Lq * G
     quant = k_scale is not None
+    # (B, Lq, KV, G, dk) -> (B, KV, Lq*G, dk): one 2-D row block per head
+    q_rows = jnp.swapaxes(q, 1, 2).reshape(B, KV, rows, dk)
 
     in_specs = [
-        pl.BlockSpec((1,), lambda b, h, s: (b,)),
-        pl.BlockSpec((1,), lambda b, h, s: (0,)),
-        pl.BlockSpec((1, Lq, 1, G, dk), lambda b, h, s: (b, 0, h, 0, 0)),
-        pl.BlockSpec((1, block_s, 1, dk), lambda b, h, s: (b, s, h, 0)),
-        pl.BlockSpec((1, block_s, 1, dv), lambda b, h, s: (b, s, h, 0)),
+        pl.BlockSpec((None, KV, rows, dk), lambda b, s, *_: (b, 0, 0, 0)),
+        pl.BlockSpec((None, block_s, KV, dk), lambda b, s, *_: (b, s, 0, 0)),
+        pl.BlockSpec((None, block_s, KV, dv), lambda b, s, *_: (b, s, 0, 0)),
     ]
-    args = [lengths, _window_arg(window), q, k_cache, v_cache]
+    args = [q_rows, k_cache, v_cache]
     if quant:
-        kern = functools.partial(_query_kernel_int8, block_s=block_s,
-                                 n_s=n_s, n_q=Lq, scale=scale)
-        in_specs += [
-            pl.BlockSpec((1, block_s, 1), lambda b, h, s: (b, s, h)),
-            pl.BlockSpec((1, block_s, 1), lambda b, h, s: (b, s, h)),
-        ]
+        in_specs += [pl.BlockSpec((None, block_s, KV),
+                                  lambda b, s, *_: (b, s, 0))] * 2
         args += [k_scale, v_scale]
-    else:
-        kern = functools.partial(_query_kernel, block_s=block_s, n_s=n_s,
-                                 n_q=Lq, scale=scale)
-    return pl.pallas_call(
+    kern = functools.partial(_query_kernel, block_s=block_s, n_s=n_s,
+                             n_q=Lq, G=G, scale=dk ** -0.5, quant=quant)
+    out = pl.pallas_call(
         kern,
-        grid=(B, KV, n_s),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Lq, 1, G, dv),
-                               lambda b, h, s: (b, 0, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Lq, KV, G, dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((Lq, G, 1), jnp.float32),
-            pltpu.VMEM((Lq, G, 1), jnp.float32),
-            pltpu.VMEM((Lq, G, dv), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, n_s),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, KV, rows, dv),
+                                   lambda b, s, *_: (b, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((KV, rows, 1), jnp.float32),
+                pltpu.VMEM((KV, rows, 1), jnp.float32),
+                pltpu.VMEM((KV, rows, dv), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, KV, rows, dv), q.dtype),
         interpret=interpret,
-    )(*args)
+    )(lengths.astype(jnp.int32), _window_arg(window), *args)
+    return jnp.swapaxes(out.reshape(B, KV, Lq, G, dv), 1, 2)
+
+
+def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                     lengths: jax.Array, *, window=GLOBAL,
+                     block_s: int = 128, interpret: bool = False,
+                     k_scale: jax.Array = None, v_scale: jax.Array = None
+                     ) -> jax.Array:
+    """Flash-decode of one query token per item. Returns (B, KV, G, dv).
+
+    The single-token case of `decode_query_attention` (Lq = 1: the query
+    sits at position lengths - 1). With k_scale/v_scale (B, S, KV) given,
+    k_cache/v_cache are int8 and are dequantized in-register (HBM streams
+    1 B/elem)."""
+    return decode_query_attention(
+        q[:, None], k_cache, v_cache, lengths, window=window,
+        block_s=block_s, interpret=interpret, k_scale=k_scale,
+        v_scale=v_scale)[:, 0]

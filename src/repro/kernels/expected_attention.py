@@ -10,8 +10,12 @@ model's *future-query distribution* q ~ N(mu_h, diag(sig2_h)):
 aggregated (mean) over the query heads h attached to the KV head. Offline,
 the top (1 - ratio) fraction of positions per item is kept.
 
-The kernel is two MXU matmuls per tile: K (bs, dk) x mu^T (dk, H) and
-K^2 (bs, dk) x sig2^T (dk, H), a log-domain add, and a mean over H.
+Grid (B, S/block_s). Each step takes a (block_s, KV, dk) K tile with every
+KV head in it, so the minor two block dimensions are the array's own
+(KV, dk) — the layout the TPU's tiling rule accepts at any head count.
+Per head the kernel runs two MXU matmuls, K (bs, dk) x mu^T (dk, G) and
+K^2 (bs, dk) x sig2^T (dk, G), a log-domain add and a mean over G, and
+writes that head's column of the (block_s, KV) score tile.
 """
 from __future__ import annotations
 
@@ -20,19 +24,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 
 def _ea_kernel(k_ref, mu_ref, sig2_ref, o_ref, *, scale: float):
-    k = k_ref[0, :, 0].astype(jnp.float32)                 # (bs, dk)
-    mu = mu_ref[0].astype(jnp.float32)                     # (G, dk)
-    sig2 = sig2_ref[0].astype(jnp.float32)                 # (G, dk)
-    lin = jax.lax.dot_general(k, mu, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    quad = jax.lax.dot_general(k * k, sig2, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    log_score = lin * scale + 0.5 * quad * (scale * scale)  # (bs, G)
-    o_ref[0, :, 0] = jnp.mean(log_score, axis=1)
+    for h in range(k_ref.shape[1]):
+        k = k_ref[:, h, :].astype(jnp.float32)             # (bs, dk)
+        mu = mu_ref[h].astype(jnp.float32)                 # (G, dk)
+        sig2 = sig2_ref[h].astype(jnp.float32)             # (G, dk)
+        lin = jax.lax.dot_general(k, mu, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        quad = jax.lax.dot_general(k * k, sig2, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        log_score = lin * scale + 0.5 * quad * (scale * scale)  # (bs, G)
+        o_ref[:, h:h + 1] = jnp.mean(log_score, axis=1, keepdims=True)
 
 
 def expected_attention_scores(k_cache: jax.Array, mu: jax.Array,
@@ -47,18 +51,17 @@ def expected_attention_scores(k_cache: jax.Array, mu: jax.Array,
     block_s = min(block_s, S)
     if S % block_s:
         raise ValueError(f"S={S} not a multiple of block_s={block_s}")
-    scale = dk ** -0.5
 
-    kernel = functools.partial(_ea_kernel, scale=scale)
+    kernel = functools.partial(_ea_kernel, scale=dk ** -0.5)
     return pl.pallas_call(
         kernel,
-        grid=(B, KV, S // block_s),
+        grid=(B, S // block_s),
         in_specs=[
-            pl.BlockSpec((1, block_s, 1, dk), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, G, dk), lambda b, h, s: (h, 0, 0)),
-            pl.BlockSpec((1, G, dk), lambda b, h, s: (h, 0, 0)),
+            pl.BlockSpec((None, block_s, KV, dk), lambda b, s: (b, s, 0, 0)),
+            pl.BlockSpec((KV, G, dk), lambda b, s: (0, 0, 0)),
+            pl.BlockSpec((KV, G, dk), lambda b, s: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_s, 1), lambda b, h, s: (b, s, h)),
+        out_specs=pl.BlockSpec((None, block_s, KV), lambda b, s: (b, s, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, KV), jnp.float32),
         interpret=interpret,
     )(k_cache, mu, sig2)

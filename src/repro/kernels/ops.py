@@ -52,6 +52,18 @@ def resolve_backend(backend=None) -> str:
     return backend
 
 
+def runs_ref(backend) -> bool:
+    """True when `backend` (already resolved) computes with the jnp
+    oracle on this host: `ref`, or `auto` off the TPU."""
+    return backend == "ref" or (backend == "auto" and not _on_tpu())
+
+
+def _clamp_window(window):
+    """A python-int window past GLOBAL means full attention: clamp it so
+    it never overflows the int32 the mask is computed in."""
+    return min(window, GLOBAL) if isinstance(window, int) else window
+
+
 def _dequant(x, scale):
     import jax.numpy as jnp
     return x.astype(jnp.float32) * scale[..., None]
@@ -62,7 +74,8 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window=GLOBAL,
                      k_scale=None, v_scale=None):
     """Single-query flash-decode; (B, KV, G, dk) -> (B, KV, G, dv)."""
     backend = resolve_backend(backend)
-    if backend == "ref" or (backend == "auto" and not _on_tpu()):
+    window = _clamp_window(window)
+    if runs_ref(backend):
         if k_scale is not None:
             k_cache = _dequant(k_cache, k_scale)
             v_cache = _dequant(v_cache, v_scale)
@@ -80,7 +93,8 @@ def decode_query_attention(q, k_cache, v_cache, lengths, *, window=GLOBAL,
     """Fused multi-token query decode; (B, Lq, KV, G, dk) ->
     (B, Lq, KV, G, dv). `lengths` includes the Lq query tokens."""
     backend = resolve_backend(backend)
-    if backend == "ref" or (backend == "auto" and not _on_tpu()):
+    window = _clamp_window(window)
+    if runs_ref(backend):
         if k_scale is not None:
             k_cache = _dequant(k_cache, k_scale)
             v_cache = _dequant(v_cache, v_scale)
@@ -95,7 +109,7 @@ def decode_query_attention(q, k_cache, v_cache, lengths, *, window=GLOBAL,
 def prefill_attention(q, k, v, *, window=GLOBAL, causal: bool = True,
                       backend=None):
     backend = resolve_backend(backend)
-    if backend == "ref" or (backend == "auto" and not _on_tpu()):
+    if runs_ref(backend):
         return ref.prefill_attention_ref(q, k, v, window=window,
                                          causal=causal)
     interpret = (backend == "interpret") or not _on_tpu()
@@ -105,7 +119,7 @@ def prefill_attention(q, k, v, *, window=GLOBAL, causal: bool = True,
 
 def expected_attention_scores(k_cache, mu, sig2, *, backend=None):
     backend = resolve_backend(backend)
-    if backend == "ref" or (backend == "auto" and not _on_tpu()):
+    if runs_ref(backend):
         return ref.expected_attention_scores_ref(k_cache, mu, sig2)
     interpret = (backend == "interpret") or not _on_tpu()
     return _ea_pl(k_cache, mu, sig2, interpret=interpret)

@@ -28,7 +28,7 @@ from repro.configs.base import ModelConfig, ShapeConfig
 from repro.distributed import sharding as sh
 from repro.launch import specs as SP
 from repro.launch.hlo_stats import analyze
-from repro.launch.mesh import TPU_V5E, make_production_mesh
+from repro.launch.mesh import TPU_V5E, make_mesh, make_production_mesh
 from repro.models import cache_axes, decode_step, param_axes, prefill
 from repro.training.optimizer import opt_state_axes
 from repro.training.train_step import make_train_step
@@ -121,15 +121,14 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
     shape = SHAPES[shape_name]
     if opts and "tp" in opts:
         # perf-iteration knob: re-balance the 256 chips between DP and TP
-        import jax as _jax
         tp = int(opts["tp"])
         total = 512 if multi_pod else 256
         per_pod = total // (2 if multi_pod else 1)
         if multi_pod:
-            mesh = _jax.make_mesh((2, per_pod // tp, tp),
-                                  ("pod", "data", "model"))
+            mesh = make_mesh((2, per_pod // tp, tp),
+                             ("pod", "data", "model"))
         else:
-            mesh = _jax.make_mesh((per_pod // tp, tp), ("data", "model"))
+            mesh = make_mesh((per_pod // tp, tp), ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     n_dev = mesh.devices.size
@@ -153,8 +152,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # jax < 0.5 returns [dict]
-        ca = ca[0] if ca else {}
     hlo = analyze(compiled.as_text())
 
     # roofline terms (per device; hlo stats are already per-device)
@@ -225,10 +222,11 @@ def main():
             for shape in applicable_shapes(cfg):
                 cells.append((arch, shape.name))
     else:
-        assert args.arch and args.shape
+        if not (args.arch and args.shape):
+            ap.error("give --arch and --shape, or --all")
         cells = [(args.arch, args.shape)]
 
-    records = []
+    n_failed = 0
     for arch, shape in cells:
         try:
             rec = run_cell(arch, shape, multi_pod=args.multi_pod,
@@ -237,7 +235,7 @@ def main():
             rec = {"arch": arch, "shape": shape, "ok": False,
                    "error": f"{type(e).__name__}: {e}"}
             print(json.dumps(rec))
-        records.append(rec)
+        n_failed += not rec["ok"]
         if args.out:
             os.makedirs(args.out, exist_ok=True)
             tag = "mp" if args.multi_pod else "sp"
@@ -247,7 +245,8 @@ def main():
                 json.dump(rec, f, indent=2)
             print(f"[dryrun] {arch} x {shape} ({tag}) -> "
                   f"{'OK' if rec.get('ok') else 'FAIL'}")
+    return 1 if n_failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

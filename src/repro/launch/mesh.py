@@ -6,29 +6,37 @@ this module never touches jax device state. The dry-run entrypoint sets
 import; everything else sees the real device count.
 
 This module is also the single source of hardware peak numbers: the
-dry-run roofline (launch/dryrun.py) and the kernels/dispatch perf gates
-(benchmarks/roofline.py) both price against a `HardwarePeaks` set from
-here — `resolve_peaks()` applies the ``STRETTO_ROOFLINE_*`` env
-overrides and names the resulting set, so every roofline report can say
-which peaks it measured against.
+dry-run roofline (launch/dryrun.py) and the kernels perf gate
+(benchmarks/roofline.py) both price against the `HardwarePeaks` that
+`peaks_for()` looks up by `device_kind`. A device that is not in the
+table is an error, never a default.
 """
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
+from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> "jax.sharding.Mesh":
+    """`jax.make_mesh` with Auto axes: the sharding rules place arrays
+    through `with_sharding_constraint`, which Explicit axes (the
+    default of `jax.make_mesh`) refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> "jax.sharding.Mesh":
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh() -> "jax.sharding.Mesh":
     """1-device mesh with the production axis names (CPU tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def make_dispatch_mesh(n_shards: int) -> "jax.sharding.Mesh":
@@ -56,27 +64,32 @@ class HardwarePeaks:
     flops: float        # FLOP/s (per chip)
     hbm_bw: float       # B/s (per chip)
     ici_bw: float = 0.0  # B/s per interconnect link (0: single chip)
+    source: str = ""
 
 
-# TPU v5e per-chip peaks — what the dry-run roofline prices against
-TPU_V5E = HardwarePeaks("tpu-v5e", flops=197e12, hbm_bw=819e9, ici_bw=50e9)
+# Per-chip peaks keyed by `jax.Device.device_kind`.
+PEAKS_BY_KIND = {
+    "TPU v5 lite": HardwarePeaks(
+        "tpu-v5e", flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "819 GB/s HBM, 1,600 Gbit/s ICI over 4 links"),
+    # deliberately conservative CPU-class bound for CI runners: a CPU
+    # roofline is a sanity bound on the runner, never a device metric
+    "cpu": HardwarePeaks("ci-cpu", flops=100e9, hbm_bw=20e9,
+                         source="conservative CI-runner bound"),
+}
+TPU_V5E = PEAKS_BY_KIND["TPU v5 lite"]
 
-# deliberately conservative CPU-class peaks — what the CI perf gates on
-# CPU runners price against (a bound that is meaningful on the runner)
-CI_CPU = HardwarePeaks("ci-cpu", flops=100e9, hbm_bw=20e9)
 
-
-def resolve_peaks(default: HardwarePeaks = CI_CPU) -> HardwarePeaks:
-    """The peak set a roofline run prices against: `default` unless the
-    ``STRETTO_ROOFLINE_GFLOPS`` / ``STRETTO_ROOFLINE_BW_GBS`` env
-    overrides are set (a TPU run gates against HBM bandwidth by
-    exporting them); the returned name records that overrides applied."""
-    gflops = os.environ.get("STRETTO_ROOFLINE_GFLOPS")
-    bw_gbs = os.environ.get("STRETTO_ROOFLINE_BW_GBS")
-    if gflops is None and bw_gbs is None:
-        return default
-    return HardwarePeaks(
-        name=f"{default.name}+env",
-        flops=float(gflops) * 1e9 if gflops else default.flops,
-        hbm_bw=float(bw_gbs) * 1e9 if bw_gbs else default.hbm_bw,
-        ici_bw=default.ici_bw)
+def peaks_for(device_kind: Optional[str] = None) -> HardwarePeaks:
+    """The peak set of `device_kind` (default: the first JAX device's).
+    Raises ValueError for a device the table does not list."""
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAKS_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak numbers for device_kind {device_kind!r}; add its "
+            f"published peaks to PEAKS_BY_KIND (known: "
+            f"{sorted(PEAKS_BY_KIND)})") from None

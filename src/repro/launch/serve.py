@@ -24,6 +24,7 @@ import numpy as np
 from repro.api import Session, SessionConfig
 from repro.core import PlannerConfig
 from repro.data.synthetic import make_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.scheduler import TenantSpec
 
 
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--precision", type=float, default=0.7)
     args = ap.parse_args()
     ratios = tuple(float(r) for r in args.ratios.split(","))
+    enable_compile_cache()
 
     ds = make_dataset("serve", args.items, seed=0)
     session = Session(SessionConfig(

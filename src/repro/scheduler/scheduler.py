@@ -36,6 +36,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
+from repro.remote.client import RemoteEngineError
 from repro.scheduler.hub import FlushHub
 from repro.scheduler.tenants import TenantSpec, validate_tenants
 
@@ -360,8 +361,11 @@ class QueryScheduler:
         for eng, model, ratio, quant in self._stage_engines(plan, h.query):
             try:
                 batches += eng.warm(model, ratio, ids, quant=quant)
-            except Exception:
-                continue      # warm is best-effort; the query still runs
+            except RemoteEngineError as e:
+                if not e.transport:
+                    raise
+                # an unreachable worker warms nothing; the query still
+                # runs (and falls back). Device errors always surface.
         with self._lock:
             st.warm_batches += batches
 
@@ -373,8 +377,9 @@ class QueryScheduler:
         for eng, model, ratio, quant in self._stage_engines(plan, h.query):
             try:
                 n += eng.evict(model, ratio, quant=quant)
-            except Exception:
-                continue
+            except RemoteEngineError as e:
+                if not e.transport:
+                    raise
         with self._lock:
             st.evictions += n
 

@@ -138,8 +138,10 @@ class ServingEngine:
         self.donated_bytes = 0
         self._xfer_lock = threading.Lock()
         self._xfer_tl = threading.local()
-        # device-resident profile cache: (profile.tag, ids, headroom) ->
-        # (cache pytree on device, nbytes). One lock serializes
+        # device-resident profile cache: (profile.tag, ids, headroom,
+        # device id) -> (cache pytree on that device, nbytes); the device
+        # is part of the key so a batch placed on one device is never
+        # handed to a flush placed on another. One lock serializes
         # lookup-or-load so concurrent flushes of the same key load once
         # and total kv_bytes stays schedule-independent.
         self._dev_cache: "OrderedDict[Tuple, Tuple[Dict[str, Any], int]]" \
@@ -401,7 +403,10 @@ class ServingEngine:
                 em.cfg, profile, ids, pad_to_multiple=KERNEL_BLOCK_S,
                 headroom=headroom, n_real=n_real)
             return cache
-        key = (profile.tag, tuple(ids), headroom)
+        placement = self._placement()
+        dev = None if placement is None else getattr(placement[0], "id",
+                                                     placement[0])
+        key = (profile.tag, tuple(ids), headroom, dev)
         with self._dev_lock:
             hit = self._dev_cache.get(key)
             if hit is not None:
@@ -434,6 +439,39 @@ class ServingEngine:
                                  headroom=len(query_tokens) + 2,
                                  n_real=len(ids))
 
+    def _flush_fn(self, model_name: str):
+        """(jitted decode fn, fused, donate) for a flush of this model."""
+        fused = self.fused and supports_fused_decode(
+            self.models[model_name].cfg)
+        # donation needs exclusive ownership of the cache buffers: the
+        # device-resident LRU would hand the same buffers to the next hit
+        donate = self.async_h2d and not self.device_cache
+        fn = self._decode_fn(model_name, fused,
+                             KOPS.resolve_backend(self.kernels), donate)
+        return fn, fused, donate
+
+    def lower_flush(self, model_name: str, profile_ratio: float,
+                    item_ids: Sequence[int], query_tokens: Sequence[int],
+                    quant: bool = False) -> "jax.stages.Lowered":
+        """The decode program one flush of `item_ids` would run, lowered
+        but not executed: the same jitted function, kernels backend,
+        params and padded cache shapes as `run_filter` / `run_map`.
+        `.as_text()` shows which attention kernel the flush calls (a
+        compiled Pallas kernel appears as `tpu_custom_call`)."""
+        em = self.models[model_name]
+        profile = Profile(model_name, profile_ratio, quant)
+        ids = list(item_ids)
+        bs = self._batch_size(profile, ids)
+        pad = max(0, min(_bucket(len(ids)), bs) - len(ids))
+        fn, _, _ = self._flush_fn(model_name)
+        placement = self._placement()
+        with self._device_ctx(placement):
+            cache = self._load_for(em, profile, ids, query_tokens, bs)
+            q = jnp.asarray([list(query_tokens)] * (len(ids) + pad),
+                            jnp.int32)
+            return fn.lower(self._params_for(em, model_name, placement),
+                            cache, q)
+
     def _flush(self, em: EngineModel, profile: Profile, ids: List[int],
                query_tokens: Sequence[int], bs: int, preloaded=None):
         """One decode flush: load (or device-cache-hit, or take the
@@ -441,12 +479,7 @@ class ServingEngine:
         NOT yet forced to host — callers np.asarray when they consume,
         which is what lets the next batch's H2D hide behind the decode)."""
         pad = max(0, min(_bucket(len(ids)), bs) - len(ids))
-        fused = self.fused and supports_fused_decode(em.cfg)
-        backend = KOPS.resolve_backend(self.kernels)
-        # donation needs exclusive ownership of the cache buffers: the
-        # device-resident LRU would hand the same buffers to the next hit
-        donate = self.async_h2d and not self.device_cache
-        fn = self._decode_fn(profile.model_name, fused, backend, donate)
+        fn, fused, donate = self._flush_fn(profile.model_name)
         placement = self._placement()
         with self._device_ctx(placement):
             cache = preloaded if preloaded is not None else \

@@ -158,3 +158,32 @@ def test_prune_dominated():
     ratios = {p["ratio"] for p in kept}
     assert 0.5 not in ratios
     assert {0.0, 0.3, 0.8} <= ratios
+
+
+def test_bf16_profile_survives_reopen(tmp_path):
+    """A bf16 profile written by one store reads back as bf16, bit for
+    bit, through a fresh CacheStore over the same directory (.npz alone
+    would return raw 2-byte voids)."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(0)
+    k = rng.normal(size=(2, 12, 2, 16)).astype(jnp.bfloat16)
+    v = rng.normal(size=(2, 12, 2, 16)).astype(jnp.bfloat16)
+    q8 = rng.integers(-127, 128, size=(2, 12, 2, 16)).astype(np.int8)
+    scale = rng.random((2, 12, 2)).astype(np.float32)
+    CacheStore(str(tmp_path)).save(Profile("m", 0.5), 3, {"k": k, "v": v},
+                                   12)
+    CacheStore(str(tmp_path)).save(Profile("m", 0.5, quant=True), 3,
+                                   {"k": q8, "k_scale": scale}, 12)
+    fresh = CacheStore(str(tmp_path))
+    got = fresh.load(Profile("m", 0.5), 3)
+    assert got["k"].dtype == jnp.bfloat16 and got["v"].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(got["k"].view(np.uint16),
+                                  k.view(np.uint16))
+    assert int(got["__length__"]) == 12
+    quant = fresh.load(Profile("m", 0.5, quant=True), 3)
+    assert quant["k"].dtype == np.int8
+    assert quant["k_scale"].dtype == np.float32
+    cache, _ = fresh.load_batch(planted_config("sm"), Profile("m", 0.5),
+                                [3, 3])
+    assert cache["k"].dtype == jnp.bfloat16
+    assert cache["k"].shape == (2, 2, 32, 2, 16)

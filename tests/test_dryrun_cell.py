@@ -26,3 +26,17 @@ def test_dryrun_cell_compiles(arch, shape):
     assert rec["roofline"]["dominant"] in ("compute", "memory",
                                            "collective")
     assert rec["hlo_flops_per_dev"] > 0
+
+
+def test_dryrun_failing_cell_exits_nonzero():
+    """A cell that fails is recorded as ok=false AND fails the process,
+    so a script or CI job running the dry run cannot miss it."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.dryrun",
+         "--arch", "no-such-arch", "--shape", "decode_32k"],
+        capture_output=True, text=True, timeout=120, env=env, cwd=ROOT)
+    assert out.returncode != 0
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    assert rec["ok"] is False and rec["arch"] == "no-such-arch"

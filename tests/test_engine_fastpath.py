@@ -174,6 +174,52 @@ def test_device_cache_hit_skips_load_and_kv_bytes(engine):
         eng.device_cache_clear()
 
 
+def test_device_cache_keyed_by_device(engine):
+    """A batch cached for one placement is never handed to a flush
+    placed on another device: the device is part of the LRU key."""
+    import jax
+    eng, ds = engine
+    ids = _ids(ds, 8)
+    query = [filter_query_token(2)]
+    dev = jax.devices()[-1]
+    try:
+        eng.device_cache = True
+        eng.device_cache_clear()
+        m0 = eng.dev_cache_misses
+        unplaced = eng.run_filter("sm", 0.0, ids, query, TOK_YES, TOK_NO)
+        with eng.place_on(dev):
+            placed = eng.run_filter("sm", 0.0, ids, query, TOK_YES, TOK_NO)
+            again = eng.run_filter("sm", 0.0, ids, query, TOK_YES, TOK_NO)
+        assert eng.dev_cache_misses - m0 == 2        # one per placement
+        assert {k[-1] for k in eng._dev_cache} == {None, dev.id}
+        for key, (cache, _) in eng._dev_cache.items():
+            if key[-1] == dev.id:
+                assert cache["k"].devices() == {dev}
+        np.testing.assert_array_equal(unplaced, placed)
+        np.testing.assert_array_equal(placed, again)
+    finally:
+        eng.device_cache = False
+        eng.device_cache_clear()
+
+
+def test_lower_flush_follows_kernels_backend(engine):
+    """lower_flush lowers the program a flush runs, with the engine's
+    kernels backend: the Pallas (interpret) and ref programs differ."""
+    eng, ds = engine
+    ids = _ids(ds, 5)
+    query = [filter_query_token(1)]
+    kernels0 = eng.kernels
+    try:
+        texts = {}
+        for backend in ("ref", "interpret"):
+            eng.kernels = backend
+            texts[backend] = eng.lower_flush("sm", 0.5, ids, query).as_text()
+        assert texts["ref"] != texts["interpret"]
+        assert "tpu_custom_call" not in texts["ref"]
+    finally:
+        eng.kernels = kernels0
+
+
 def test_device_cache_disabled_always_loads(engine):
     eng, ds = engine
     assert not eng.device_cache        # suite default (conftest)

@@ -15,7 +15,7 @@ from hypothesis_compat import given, settings, st
 
 import jax
 
-from repro.launch.mesh import make_dispatch_mesh
+from repro.launch.mesh import make_dispatch_mesh, make_local_mesh, peaks_for
 from repro.runtime.dispatch import (MeshDispatcher, ShardedDispatcher,
                                     backend_engines, resolve_dispatcher)
 
@@ -53,6 +53,24 @@ def test_dispatch_mesh_axes_and_size():
     assert all(dev in jax.devices() for dev in devs)
     if n_dev >= 2:
         assert len(set(devs)) >= 2
+
+
+def test_meshes_use_auto_axes():
+    """Sharding rules place arrays with with_sharding_constraint, which
+    Explicit mesh axes (jax.make_mesh's default) refuse."""
+    from jax.sharding import AxisType
+    for mesh in (make_local_mesh(), make_dispatch_mesh(2)):
+        assert set(mesh.axis_types) == {AxisType.Auto}
+
+
+def test_peak_table_keyed_by_device_kind():
+    """Rooflines price against the running device's own peaks; a device
+    the table does not list is an error, never a fallback."""
+    v5e = peaks_for("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9) and v5e.source
+    assert peaks_for() == peaks_for(jax.devices()[0].device_kind)
+    with pytest.raises(ValueError, match="no peak numbers"):
+        peaks_for("TPU v99 unknown")
 
 
 # ---------------------------------------------------------------------------
