@@ -22,6 +22,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 from repro.core.logical import (Query, RelFilter, SemAgg, SemFilter,
                                 SemJoin, SemMap, SemTopK)
 from repro.core.physical import TREE_ROLES, PhysicalPlan, TreePlan
+from repro.obs import FlushCounters
 
 
 @dataclass(frozen=True)
@@ -114,11 +115,10 @@ class ExplainReport:
     measured_partitions: Optional[int] = None
     measured_dispatcher: Optional[str] = None     # what actually ran it
     measured_workers: Optional[int] = None
-    # transfer overlap telemetry (serving engines only): H2D copy time
-    # the engine hid behind decode compute, and KV cache bytes the jitted
-    # decode donated back to XLA — None until ANALYZE
-    measured_h2d_overlap_s: Optional[float] = None
-    measured_donated_bytes: Optional[int] = None
+    # the engine's flush counters summed over every stage (serving
+    # engines only: read, assemble, h2d, wait and prefetch seconds,
+    # compiles, padded and donated bytes) — None until ANALYZE
+    measured_flush: Optional[FlushCounters] = None
     # per-engine measured totals (engine, wall_s, n_tuples, n_llm_calls,
     # kv_bytes) — exact partition of the run totals; empty until ANALYZE,
     # rendered only for pooled (multi-engine-tagged) executions
@@ -222,12 +222,8 @@ class ExplainReport:
             measured_partitions=result.n_partitions,
             measured_dispatcher=result.dispatcher,
             measured_workers=result.n_workers,
-            measured_h2d_overlap_s=sum(
-                getattr(sg, "h2d_overlap_s", 0.0)
-                for sg in result.stage_stats),
-            measured_donated_bytes=sum(
-                getattr(sg, "donated_bytes", 0)
-                for sg in result.stage_stats),
+            measured_flush=sum((sg.flush for sg in result.stage_stats),
+                               FlushCounters()),
             measured_engines=per_engine,
             measured_shared_batches=sum(
                 getattr(sg, "shared_batches", 0)
@@ -337,13 +333,15 @@ class ExplainReport:
                 f"(elapsed) partitions={self.measured_partitions} "
                 f"dispatcher={self.measured_dispatcher}"
                 f":{self.measured_workers}")
-            if self.measured_h2d_overlap_s or self.measured_donated_bytes:
+            f = self.measured_flush
+            if f is not None and f != FlushCounters():
                 out.append(
-                    f"transfers: h2d_overlap_s="
-                    f"{self.measured_h2d_overlap_s:.3f} (H2D hidden "
-                    f"behind decode) donated_MB="
-                    f"{self.measured_donated_bytes / 1e6:.1f} "
-                    f"(KV buffers returned to XLA)")
+                    f"flush: read_s={f.read_s:.3f} "
+                    f"assemble_s={f.assemble_s:.3f} h2d_s={f.h2d_s:.3f} "
+                    f"wait_s={f.wait_s:.3f} prefetch_s={f.prefetch_s:.3f} "
+                    f"compiles={f.compiles} compile_s={f.compile_s:.3f} "
+                    f"padded_MB={f.padded_bytes / 1e6:.1f} "
+                    f"donated_MB={f.donated_bytes / 1e6:.1f}")
             if any(eng for eng, *_ in self.measured_engines):
                 for eng, wall, tuples, llm, kv in self.measured_engines:
                     out.append(

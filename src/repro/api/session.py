@@ -114,8 +114,8 @@ class EngineSpec:
                          engine prefetches the next flush batch's KV
                          caches while the current batch decodes and
                          donates consumed cache buffers back to XLA
-                         (surfaced as h2d_overlap_s / donated_bytes in
-                         EXPLAIN ANALYZE; None: STRETTO_ASYNC_H2D,
+                         (surfaced as prefetch_s / donated MB on
+                         EXPLAIN ANALYZE's flush line; None: STRETTO_ASYNC_H2D,
                          default on). Never changes results.
       device           — pin this engine on one device: an index into
                          jax.devices(); an index past the device count

@@ -23,7 +23,9 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
+from repro.obs import FlushCounters
 
 META_FILE = "_meta.jsonl"
 
@@ -164,7 +166,8 @@ class CacheStore:
 
     def load_batch(self, cfg: ModelConfig, profile: Profile,
                    item_ids: Sequence[int], pad_to_multiple: int = 32,
-                   headroom: int = 0, n_real: Optional[int] = None
+                   headroom: int = 0, n_real: Optional[int] = None,
+                   counters: Optional[FlushCounters] = None
                    ) -> Tuple[Dict[str, Any], np.ndarray]:
         """Assemble a right-padded decode cache for a batch of items.
 
@@ -182,8 +185,15 @@ class CacheStore:
         the counter measures the cache bytes the *scored tuples* needed —
         an exact quantity independent of how flushes were grouped — not
         the padding replicas.
+
+        The steps run in the spans `stretto.store.read`, then per cache
+        key `stretto.store.assemble` (np.pad, np.stack) and
+        `stretto.store.h2d` (the copy to the device); with `counters`
+        given, their seconds and the bytes of the padded batch are added
+        to it.
         """
-        shards = [self.load(profile, i) for i in item_ids]
+        with obs.span("stretto.store.read") as read:
+            shards = [self.load(profile, i) for i in item_ids]
         n_count = len(shards) if n_real is None else min(n_real, len(shards))
         loaded = sum(a.nbytes for s in shards[:n_count]
                      for k, a in s.items() if k != "__length__")
@@ -194,20 +204,32 @@ class CacheStore:
         smax = int(lengths.max()) + headroom
         smax = ((smax + pad_to_multiple - 1) // pad_to_multiple
                 * pad_to_multiple)
-        cache: Dict[str, Any] = {}
         seq_keys = {"k", "v", "c_kv", "k_rope", "k_scale", "v_scale"}
+        cache: Dict[str, Any] = {}
+        assemble_s = h2d_s = 0.0
         for key in shards[0]:
             if key == "__length__":
                 continue
-            per = []
-            for s in shards:
-                a = s[key]
-                if key in seq_keys:   # (L, S', ...) -> pad S' to smax
-                    pad = [(0, 0)] * a.ndim
-                    pad[1] = (0, smax - a.shape[1])
-                    a = np.pad(a, pad)
-                per.append(a)
-            stacked = np.stack(per, axis=1)       # (L, B, ...)
-            cache[key] = jnp.asarray(stacked)
-        cache["lengths"] = jnp.asarray(lengths)
+            with obs.span("stretto.store.assemble", key=key) as assemble:
+                per = []
+                for s in shards:
+                    a = s[key]
+                    if key in seq_keys:   # (L, S', ...) -> pad S' to smax
+                        pad = [(0, 0)] * a.ndim
+                        pad[1] = (0, smax - a.shape[1])
+                        a = np.pad(a, pad)
+                    per.append(a)
+                stacked = np.stack(per, axis=1)       # (L, B, ...)
+            with obs.span("stretto.store.h2d", key=key) as h2d:
+                cache[key] = jnp.asarray(stacked)
+            assemble_s += assemble.secs
+            h2d_s += h2d.secs
+        with obs.span("stretto.store.h2d", key="lengths") as h2d:
+            cache["lengths"] = jnp.asarray(lengths)
+        if counters is not None:
+            counters.read_s += read.secs
+            counters.assemble_s += assemble_s
+            counters.h2d_s += h2d_s + h2d.secs
+            counters.padded_bytes += sum(int(v.nbytes) for k, v in
+                                         cache.items() if k != "lengths")
         return cache, lengths

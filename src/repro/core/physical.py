@@ -116,6 +116,9 @@ class PhysicalPlan:
     precision_bound: float
     feasible: bool
     planning_time_s: float = 0.0
+    # planner phases' seconds (the spans stretto.plan.profile, .optimize,
+    # .order), in phase order; empty for plans not made by plan_query
+    phase_s: Dict[str, float] = field(default_factory=dict)
     # post-filters a checked pushdown could NOT move ahead of the LLM
     # stages: [(RelFilter, producing_map_logical_idx | None)]. An entry
     # with a map index filters that SemMap's extracted value; None means
@@ -128,6 +131,10 @@ class PhysicalPlan:
         lines = [f"PhysicalPlan(est_cost={self.est_cost:.2f}s, "
                  f"R>={self.recall_bound:.3f}, P>={self.precision_bound:.3f},"
                  f" feasible={self.feasible})"]
+        if self.phase_s:
+            lines.append(
+                f"  planned in {self.planning_time_s:.2f}s: "
+                + ", ".join(f"{k} {v:.2f}s" for k, v in self.phase_s.items()))
         for r in self.relational:
             lines.append(f"  rel: {r}")
         for s in self.stages:

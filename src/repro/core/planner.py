@@ -21,6 +21,7 @@ from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import ordering as ORD
 from repro.core import relaxation as R
 from repro.core.logical import (JoinNode, PipelineLeaf, Query, SemAgg,
@@ -182,36 +183,47 @@ def plan_query(query: Query, items: Sequence[Any], registry: Callable,
     at their *measured* mean flush width instead of the static `coalesce`
     default, both inside the gradient optimizer's differentiable cost
     (per-op, via PipelineData.meas_width) and in the DP reorderer's
-    per-stage `exp_batch`."""
+    per-stage `exp_batch`.
+
+    Planning runs in the span `stretto.plan` (with a fresh `exec` id, which
+    the profiling flushes inherit), its phases in `stretto.plan.profile`
+    (step 2), `stretto.plan.optimize` (step 3) and `stretto.plan.order`
+    (stage build and reorder); the plan records their seconds in
+    `phase_s`."""
     # default constructed per call — a shared default instance would leak
     # mutations between unrelated plans
     cfg = cfg if cfg is not None else PlannerConfig()
     t0 = time.perf_counter()
-    query = normalize(query)                              # step 1 (checked)
-    sem_ops = query.semantic_ops
-    profiles, sample_idx = profile_query(                 # step 2
-        query, items, registry, sample_frac, seed)
-    _shift_topk_gold(profiles, sem_ops, len(items))
-    g = gold_membership(profiles)
-    pipelines = pipelines_data(profiles, measured, sem_ops=sem_ops)
-    # batch-size-aware costing: amortize fixed per-call cost over the
-    # coalesced flush batches the streaming executor will actually run.
-    # The hint width is the static coalesce default unless the measured
-    # store has seen these ops execute, in which case their tuple-weighted
-    # measured flush width seeds the hint (per-op measured widths override
-    # it again inside the relaxation where individual ops were recorded).
-    hint = R.BatchHint(width=_hint_width(profiles, coalesce, measured),
-                       scale=len(items) / max(len(sample_idx), 1))
-    t_rec, t_prec = _effective_targets(query, items)
-    plan = optimize_query(pipelines, g,                   # step 3
-                          t_rec, t_prec, cfg,
-                          batch_hint=hint)
-    sel = estimate_selectivities(profiles, plan, sem_ops=sem_ops)
-
-    # build stage list (cascades in cost order) for the DP reorderer
-    phys_ops, stage_meta = _build_stages(
-        profiles, plan, sel, hint, len(items), measured, sem_ops)
-    stages = _order_stages(phys_ops, stage_meta, len(items), reorder)
+    with obs.span("stretto.plan", exec=obs.new_exec_id()):
+        query = normalize(query)                          # step 1 (checked)
+        sem_ops = query.semantic_ops
+        with obs.span("stretto.plan.profile") as profile:  # step 2
+            profiles, sample_idx = profile_query(
+                query, items, registry, sample_frac, seed)
+        _shift_topk_gold(profiles, sem_ops, len(items))
+        g = gold_membership(profiles)
+        pipelines = pipelines_data(profiles, measured, sem_ops=sem_ops)
+        # batch-size-aware costing: amortize fixed per-call cost over the
+        # coalesced flush batches the streaming executor will actually
+        # run. The hint width is the static coalesce default unless the
+        # measured store has seen these ops execute, in which case their
+        # tuple-weighted measured flush width seeds the hint (per-op
+        # measured widths override it again inside the relaxation where
+        # individual ops were recorded).
+        hint = R.BatchHint(width=_hint_width(profiles, coalesce, measured),
+                           scale=len(items) / max(len(sample_idx), 1))
+        t_rec, t_prec = _effective_targets(query, items)
+        with obs.span("stretto.plan.optimize") as optimize:  # step 3
+            plan = optimize_query(pipelines, g, t_rec, t_prec, cfg,
+                                  batch_hint=hint)
+        with obs.span("stretto.plan.order") as order:
+            sel = estimate_selectivities(profiles, plan, sem_ops=sem_ops)
+            # build stage list (cascades in cost order) for the DP
+            # reorderer
+            phys_ops, stage_meta = _build_stages(
+                profiles, plan, sel, hint, len(items), measured, sem_ops)
+            stages = _order_stages(phys_ops, stage_meta, len(items),
+                                   reorder)
 
     return PhysicalPlan(
         stages=stages, relational=leading_relational(query),
@@ -220,6 +232,8 @@ def plan_query(query: Query, items: Sequence[Any], registry: Callable,
         precision_bound=plan.precision_bound,
         feasible=plan.feasible,
         planning_time_s=time.perf_counter() - t0,
+        phase_s={"profile": profile.secs, "optimize": optimize.secs,
+                 "order": order.secs},
         post_relational=pinned_relational(query))
 
 

@@ -166,6 +166,7 @@ def decode_query_attention(q: jax.Array, k_cache: jax.Array,
             ]),
         out_shape=jax.ShapeDtypeStruct((B, KV, rows, dv), q.dtype),
         interpret=interpret,
+        name="decode_query_attention",
     )(lengths.astype(jnp.int32), _window_arg(window), *args)
     return jnp.swapaxes(out.reshape(B, KV, Lq, G, dv), 1, 2)
 

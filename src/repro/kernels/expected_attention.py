@@ -64,4 +64,5 @@ def expected_attention_scores(k_cache: jax.Array, mu: jax.Array,
         out_specs=pl.BlockSpec((None, block_s, KV), lambda b, s: (b, s, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, KV), jnp.float32),
         interpret=interpret,
+        name="expected_attention_scores",
     )(k_cache, mu, sig2)

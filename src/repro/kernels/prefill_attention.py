@@ -119,4 +119,5 @@ def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, G, dv), jnp.float32),
         ],
         interpret=interpret,
+        name="prefill_attention",
     )(q, k, v)

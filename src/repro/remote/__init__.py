@@ -5,8 +5,8 @@ The subsystem has three layers plus an integration seam:
   protocol — versioned, length-prefixed msgpack-or-JSON frames for
       score_filter / run_map / warm / evict / health / stats, carrying
       operator identity, a compression tag, item batches, and the
-      member's per-call telemetry deltas (kv_bytes, attn_dispatches,
-      h2d_overlap_s, donated_bytes) so per-engine StageStats stay exact
+      member's per-call telemetry deltas (kv_bytes, attn_dispatches and
+      the engine's flush counters) so per-engine StageStats stay exact
       end to end.
   server — a threaded socket server (RemoteWorker) wrapping one local
       ServingEngine + KVCacheBackend, building profiles lazily on the

@@ -36,11 +36,12 @@ import socket
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
 from repro.core.logical import SemFilter, SemJoin, SemMap
+from repro.obs import FlushCounters
 from repro.remote.protocol import (HAVE_MSGPACK, PROTOCOL_VERSION,
                                    ProtocolError, corpus_hash, items_to_wire,
                                    recv_msg, send_msg, sem_to_wire)
@@ -365,10 +366,8 @@ class RemoteEngineMember(RegistryBackend):
         tl = self._flush_tl
         tl.kv_bytes = getattr(tl, "kv_bytes", 0) \
             + int(stats.get("kv_bytes", 0))
-        tl.h2d_s = getattr(tl, "h2d_s", 0.0) \
-            + float(stats.get("h2d_overlap_s", 0.0))
-        tl.donated = getattr(tl, "donated", 0) \
-            + int(stats.get("donated_bytes", 0))
+        tl.flush = self.transfer_stats() \
+            + FlushCounters.from_dict(stats.get("flush") or {})
 
     def _wire_filter(self, op, op_name: str,
                      items: Sequence[Any]) -> np.ndarray:
@@ -405,9 +404,8 @@ class RemoteEngineMember(RegistryBackend):
         # so run_operator's before/after deltas stay exact
         return getattr(self._flush_tl, "kv_bytes", 0)
 
-    def transfer_stats(self) -> Tuple[float, int]:
-        tl = self._flush_tl
-        return (getattr(tl, "h2d_s", 0.0), getattr(tl, "donated", 0))
+    def transfer_stats(self) -> FlushCounters:
+        return getattr(self._flush_tl, "flush", None) or FlushCounters()
 
     def snapshot(self) -> Dict[str, Any]:
         """Cumulative wire counters (monotonic; diff two snapshots for a

@@ -30,8 +30,9 @@ Message verbs (all dicts with a "verb" key; responses carry "ok"):
   stats        — the worker's request counters
 
 Scoring responses return the member's telemetry deltas (kv_bytes,
-attn_dispatches, h2d_overlap_s, donated_bytes, server_wall_s) so the
-client can keep per-engine StageStats exact end to end.
+attn_dispatches, server_wall_s, and the engine's FlushCounters as one
+dict of their field names under `flush`) so the client can keep
+per-engine StageStats exact end to end.
 """
 from __future__ import annotations
 

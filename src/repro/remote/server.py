@@ -219,16 +219,15 @@ class RemoteWorker:
         eng = self.engine
         with self._exec_lock:
             kv0 = eng.store.bytes_loaded_local
-            h2d0, don0 = eng.transfer_stats_local()
+            flush0 = eng.transfer_stats_local()
             attn0 = eng.attn_dispatches
             t0 = time.perf_counter()
             payload = runner(sem, msg["op_name"], items)
             wall = time.perf_counter() - t0
-            h2d1, don1 = eng.transfer_stats_local()
+            flush = eng.transfer_stats_local() - flush0
             stats = {"kv_bytes": eng.store.bytes_loaded_local - kv0,
                      "attn_dispatches": eng.attn_dispatches - attn0,
-                     "h2d_overlap_s": h2d1 - h2d0,
-                     "donated_bytes": don1 - don0,
+                     "flush": flush.as_dict(),
                      "server_wall_s": wall}
         with self._stats_lock:
             self.n_scores += 1

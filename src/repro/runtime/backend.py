@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.core.logical import SemFilter, SemMap
 from repro.core.physical import PhysicalOperator
+from repro.obs import FlushCounters
 
 
 @runtime_checkable
@@ -138,15 +139,15 @@ class RegistryBackend:
         # engine's store counter.
         return 0
 
-    def transfer_stats(self) -> Tuple[float, int]:
-        """Monotonic (h2d_overlap_s, donated_bytes) counters for the
-        calling thread — H2D transfer time the engine hid behind decode
-        compute, and KV cache bytes donated back to XLA. Thread-scoped
-        for the same reason as kv_bytes_loaded. Kept OFF the Backend
-        protocol (it is optional — run_operator getattr-probes it), so
-        custom backends that only implement the protocol surface keep
-        satisfying the runtime_checkable isinstance check."""
-        return (0.0, 0)
+    def transfer_stats(self) -> FlushCounters:
+        """The calling thread's monotonic FlushCounters — where the
+        engine's flushes spent host time (read, assemble, h2d, wait,
+        prefetch), their compiles, and the bytes they padded and donated.
+        Thread-scoped for the same reason as kv_bytes_loaded. Kept OFF
+        the Backend protocol (it is optional — run_operator getattr-probes
+        it), so custom backends that only implement the protocol surface
+        keep satisfying the runtime_checkable isinstance check."""
+        return FlushCounters()
 
 
 class OracleBackend(RegistryBackend):
@@ -178,7 +179,7 @@ class KVCacheBackend(RegistryBackend):
         # thread, so per-call deltas are exact under concurrent dispatch
         return self.engine.store.bytes_loaded_local
 
-    def transfer_stats(self) -> Tuple[float, int]:
+    def transfer_stats(self) -> FlushCounters:
         return self.engine.transfer_stats_local()
 
 
@@ -205,7 +206,7 @@ class ReferenceBackend(RegistryBackend):
     def kv_bytes_loaded(self) -> int:
         return self.engine.store.bytes_loaded_local
 
-    def transfer_stats(self) -> Tuple[float, int]:
+    def transfer_stats(self) -> FlushCounters:
         return self.engine.transfer_stats_local()
 
 
@@ -327,15 +328,14 @@ class PoolBackend(RegistryBackend):
         # contributes its delta to exactly one term
         return sum(m.kv_bytes_loaded() for m in self.members.values())
 
-    def transfer_stats(self) -> Tuple[float, int]:
-        h2d, donated = 0.0, 0
+    def transfer_stats(self) -> FlushCounters:
+        # per-thread sum over members, like kv_bytes_loaded
+        total = FlushCounters()
         for m in self.members.values():
             fn = getattr(m, "transfer_stats", None)
             if fn is not None:
-                mh, md = fn()
-                h2d += mh
-                donated += md
-        return (h2d, donated)
+                total = total + fn()
+        return total
 
 
 def as_backend(registry_or_backend) -> Backend:

@@ -84,6 +84,8 @@ class FlushTask:
     #                          global submission (FIFO) order regardless
     #                          of which pool ran a task, per-engine
     #                          routing preserves submission-order parity
+    exec_id: Optional[int] = None  # the submitting execution's id (the
+    #                          `exec` argument of its spans)
 
 
 class _Immediate:

@@ -46,6 +46,7 @@ incremental-delivery path the api layer's ``SemFrame.stream()`` exposes.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -54,11 +55,13 @@ from typing import (Any, Deque, Dict, Generator, List, Optional, Sequence,
 
 import numpy as np
 
+from repro import obs
 from repro.core.logical import Query, SemFilter, SemMap, SemTopK
 from repro.core.physical import PhysicalPlan, PhysicalPlanStage
 from repro.runtime.backend import Backend, as_backend
 from repro.runtime.dispatch import (DEFAULT_COALESCE, FlushTask,
                                     InlineDispatcher, resolve_dispatcher)
+from repro.obs import FlushCounters
 from repro.runtime.kernel import decide, gold_decide
 
 
@@ -84,14 +87,6 @@ class StageStats:
     #                            a stage runs on exactly one engine, so
     #                            grouping stage rows by this field yields
     #                            exact per-engine cost / KV-bytes totals
-    h2d_overlap_s: float = 0.0  # H2D transfer time hidden behind decode
-    #                            compute by the engine's async prefetch —
-    #                            time that WOULD have serialized with
-    #                            wall_s but did not (counted per flush on
-    #                            the dispatching thread, like kv_bytes)
-    donated_bytes: int = 0     # bytes of consumed KV cache buffers the
-    #                            jitted decode donated back to XLA
-    #                            (donate_argnums) instead of holding live
     shared_batches: int = 0    # flushes of this stage that executed as
     #                            part of a merged cross-query engine call
     #                            (scheduler coalescing) — 0 for solo runs
@@ -99,6 +94,12 @@ class StageStats:
     #                            participating queries' segments), so
     #                            shared_width / shared_batches is the
     #                            mean coalesced batch this query rode in
+    flush: FlushCounters = field(default_factory=FlushCounters)
+    #                            the engine's per-flush counters (read,
+    #                            assemble, h2d, wait and prefetch seconds,
+    #                            compiles, padded and donated bytes),
+    #                            summed like kv_bytes from per-thread
+    #                            deltas taken on the dispatching thread
 
     @property
     def mean_batch(self) -> float:
@@ -112,8 +113,7 @@ class StageStats:
         self.n_tuples += n_scored
         self.n_batches += 1
         self.kv_bytes += out.kv_bytes
-        self.h2d_overlap_s += out.h2d_overlap_s
-        self.donated_bytes += out.donated_bytes
+        self.flush = self.flush + out.flush
         if out.merged_queries > 1:
             self.shared_batches += 1
             self.shared_width += out.merged_width
@@ -130,17 +130,13 @@ class StageStats:
         self.n_llm_calls += other.n_llm_calls
         self.kv_bytes += other.kv_bytes
         self.n_batches += other.n_batches
-        self.h2d_overlap_s += other.h2d_overlap_s
-        self.donated_bytes += other.donated_bytes
+        self.flush = self.flush + other.flush
         self.shared_batches += other.shared_batches
         self.shared_width += other.shared_width
 
     def copy(self) -> "StageStats":
-        return StageStats(self.op_name, self.logical_idx, self.stage,
-                          self.wall_s, self.n_tuples, self.n_llm_calls,
-                          self.kv_bytes, self.n_batches, self.engine,
-                          self.h2d_overlap_s, self.donated_bytes,
-                          self.shared_batches, self.shared_width)
+        return dataclasses.replace(self,
+                                   flush=dataclasses.replace(self.flush))
 
     def as_dict(self) -> Dict[str, Any]:
         return {"op_name": self.op_name, "logical_idx": self.logical_idx,
@@ -148,11 +144,10 @@ class StageStats:
                 "wall_s": self.wall_s,
                 "n_tuples": self.n_tuples, "n_llm_calls": self.n_llm_calls,
                 "kv_bytes": self.kv_bytes, "n_batches": self.n_batches,
-                "h2d_overlap_s": self.h2d_overlap_s,
-                "donated_bytes": self.donated_bytes,
                 "shared_batches": self.shared_batches,
                 "shared_width": self.shared_width,
-                "mean_batch": round(self.mean_batch, 2)}
+                "mean_batch": round(self.mean_batch, 2),
+                **self.flush.as_dict()}
 
 
 @dataclass
@@ -258,8 +253,7 @@ class _OperatorOutcome:
     wall_s: float
     kv_bytes: int
     uses_llm: bool
-    h2d_overlap_s: float = 0.0
-    donated_bytes: int = 0
+    flush: FlushCounters = field(default_factory=FlushCounters)
     # cross-query coalescing provenance (scheduler FlushHub): when this
     # outcome is one query's slice of a merged engine call, merged_width
     # is the merged call's total tuple count and merged_queries how many
@@ -269,36 +263,42 @@ class _OperatorOutcome:
 
 
 def run_operator(backend: Backend, op, op_name: str,
-                 items: Sequence[Any]) -> _OperatorOutcome:
+                 items: Sequence[Any], exec_id=None) -> _OperatorOutcome:
     """Invoke one physical operator on one batch, with uniform telemetry.
 
     This is the only place in the tree that calls into a backend's
     score_filter / run_map — the profiler and the streaming executor both
     batch through here, so cost and KV-bytes accounting are identical in
-    planning and execution.
+    planning and execution. The call runs in the span `stretto.run.flush`
+    (arguments: the execution id, where given, else the one of the
+    enclosing span; the operator; the tuple count).
     """
     phys = backend.resolve(op, op_name)
     kv0 = backend.kv_bytes_loaded()
-    # transfer telemetry is optional on the Backend protocol: serving
-    # backends expose (h2d_overlap_s, donated_bytes) per calling thread,
-    # oracle/custom backends simply have no transfers to report
+    # flush counters are optional on the Backend protocol: serving
+    # backends expose a per-thread FlushCounters, oracle/custom backends
+    # simply have nothing to report
     xfer = getattr(backend, "transfer_stats", None)
-    x0 = xfer() if xfer is not None else (0.0, 0)
-    t0 = time.perf_counter()
-    if isinstance(op, SemMap):
-        values, scores = backend.run_map(op, op_name, items)
-    else:
-        # filter-like: SemFilter, SemTopK (scored like a filter, accepted
-        # by rank cut) and SemJoin (pair-scoring) all return log-odds
-        scores = backend.score_filter(op, op_name, items)
-        values = None
-    wall = time.perf_counter() - t0
-    x1 = xfer() if xfer is not None else (0.0, 0)
+    x0 = xfer() if xfer is not None else None
+    args = {"op": op_name, "tuples": len(items)}
+    if exec_id is not None:
+        args["exec"] = exec_id
+    with obs.span("stretto.run.flush", **args):
+        t0 = time.perf_counter()
+        if isinstance(op, SemMap):
+            values, scores = backend.run_map(op, op_name, items)
+        else:
+            # filter-like: SemFilter, SemTopK (scored like a filter,
+            # accepted by rank cut) and SemJoin (pair-scoring) all
+            # return log-odds
+            scores = backend.score_filter(op, op_name, items)
+            values = None
+        wall = time.perf_counter() - t0
     return _OperatorOutcome(
         scores=scores, values=values, wall_s=wall,
         kv_bytes=backend.kv_bytes_loaded() - kv0,
         uses_llm=bool(getattr(phys, "uses_llm", True)),
-        h2d_overlap_s=x1[0] - x0[0], donated_bytes=x1[1] - x0[1])
+        flush=xfer() - x0 if xfer is not None else FlushCounters())
 
 
 class _CascadeState:
@@ -507,6 +507,8 @@ def iter_plan(plan: PhysicalPlan, query: Query, items: Sequence[Any],
     """
     backend = as_backend(backend)
     disp, owned = resolve_dispatcher(dispatcher)
+    # the `exec` argument every span of this execution carries
+    exec_id = obs.new_exec_id()
     try:
         # sharding dispatchers scatter the partition loop itself (a
         # 1-shard scatter degenerates to one inline streaming pass);
@@ -514,11 +516,11 @@ def iter_plan(plan: PhysicalPlan, query: Query, items: Sequence[Any],
         if hasattr(disp, "map_shards"):
             result = yield from _stream_sharded(plan, query, items, backend,
                                                 partition_size, coalesce,
-                                                disp)
+                                                disp, exec_id)
         else:
             result = yield from _stream_streaming(plan, query, items,
                                                   backend, partition_size,
-                                                  coalesce, disp)
+                                                  coalesce, disp, exec_id)
         return result
     finally:
         if owned:
@@ -536,17 +538,22 @@ def _drain(gen) -> RuntimeResult:
 
 def _run_streaming(plan: PhysicalPlan, query: Query, items: Sequence[Any],
                    backend: Backend, partition_size: Optional[int],
-                   coalesce: Optional[int], disp,
+                   coalesce: Optional[int], disp, exec_id: int,
                    topk_cut: bool = True) -> RuntimeResult:
     return _drain(_stream_streaming(plan, query, items, backend,
-                                    partition_size, coalesce, disp,
+                                    partition_size, coalesce, disp, exec_id,
                                     topk_cut=topk_cut))
 
 
 def _stream_streaming(plan: PhysicalPlan, query: Query, items: Sequence[Any],
                       backend: Backend, partition_size: Optional[int],
-                      coalesce: Optional[int], disp, topk_cut: bool = True
+                      coalesce: Optional[int], disp, exec_id: int,
+                      topk_cut: bool = True
                       ) -> Generator[PartitionResult, None, RuntimeResult]:
+    """The streaming loop. Each partition's ingestion runs in the span
+    `stretto.run.partition` and each step of the final drain in
+    `stretto.run.drain`; both close before a settled partition is
+    yielded, so no span stays open while the consumer holds one."""
     sem_ops = query.semantic_ops
     N = len(items)
     S = len(plan.stages)
@@ -646,7 +653,8 @@ def _stream_streaming(plan: PhysicalPlan, query: Query, items: Sequence[Any],
     inflight: Deque[Tuple[int, np.ndarray, np.ndarray, Any]] = deque()
 
     def runner(task: FlushTask) -> _OperatorOutcome:
-        return run_operator(backend, task.sem_op, task.op_name, task.items)
+        return run_operator(backend, task.sem_op, task.op_name, task.items,
+                            exec_id=task.exec_id)
 
     def enqueue(s: int, idx: np.ndarray):
         # a cohort with nothing for stage s to score passes straight
@@ -690,7 +698,8 @@ def _stream_streaming(plan: PhysicalPlan, query: Query, items: Sequence[Any],
         batch = [items[i] for i in run_idx]
         handle = disp.submit(
             FlushTask(s, op, st.op_name, batch,
-                      engine=getattr(st, "engine", "")), runner)
+                      engine=getattr(st, "engine", ""), exec_id=exec_id),
+            runner)
         inflight.append((s, idx, run_idx, handle))
         while len(inflight) > disp.max_pending:
             complete_oldest()
@@ -715,25 +724,30 @@ def _stream_streaming(plan: PhysicalPlan, query: Query, items: Sequence[Any],
             break
         n_parts += 1
         bounds.append((start, int(idx[-1]) + 1))
-        alive = np.ones(idx.size, bool)
-        for rel in plan.relational:
-            alive &= np.array([rel.apply(getattr(items[i], "row", {}) or {})
-                               for i in idx])
-        state.admit(idx, alive)
-        settled[idx[~alive]] = True   # relational rejects never enter
-        enqueue(0, idx[alive])
-        pump()
-        yield from emit(ready_partitions())
+        with obs.span("stretto.run.partition", exec=exec_id,
+                      part=n_parts - 1):
+            alive = np.ones(idx.size, bool)
+            for rel in plan.relational:
+                alive &= np.array([rel.apply(getattr(items[i], "row", {})
+                                             or {}) for i in idx])
+            state.admit(idx, alive)
+            settled[idx[~alive]] = True   # relational rejects never enter
+            enqueue(0, idx[alive])
+            pump()
+            parts = ready_partitions()
+        yield from emit(parts)
     # drain: a stage's final flush runs only once nothing upstream —
     # buffered or in flight — can still feed it; otherwise settle the
     # oldest in-flight flush and re-examine
     while inflight or any(pending):
-        s = next((j for j in range(S) if pending[j]), None)
-        if s is not None and not any(f[0] < s for f in inflight):
-            submit_flush(s)
-        else:
-            complete_oldest()
-        yield from emit(ready_partitions())
+        with obs.span("stretto.run.drain", exec=exec_id):
+            s = next((j for j in range(S) if pending[j]), None)
+            if s is not None and not any(f[0] < s for f in inflight):
+                submit_flush(s)
+            else:
+                complete_oldest()
+            parts = ready_partitions()
+        yield from emit(parts)
     if holdback:
         # every tuple is settled: apply (or defer) the rank cut, then
         # release all held partitions at once
@@ -805,7 +819,7 @@ def merge_stage_stats(per_shard: Sequence[Sequence[StageStats]],
 
 def _stream_sharded(plan: PhysicalPlan, query: Query, items: Sequence[Any],
                     backend: Backend, partition_size: Optional[int],
-                    coalesce: Optional[int], disp
+                    coalesce: Optional[int], disp, exec_id: int
                     ) -> Generator[PartitionResult, None, RuntimeResult]:
     """Scatter the partition loop across contiguous corpus shards.
 
@@ -850,11 +864,11 @@ def _stream_sharded(plan: PhysicalPlan, query: Query, items: Sequence[Any],
         if shard_ctx is None:
             return _run_streaming(plan, query, items[lo:hi], backend,
                                   partition_size, coalesce, inline,
-                                  topk_cut=cut)
+                                  exec_id, topk_cut=cut)
         with shard_ctx(i, backend):
             return _run_streaming(plan, query, items[lo:hi], backend,
                                   partition_size, coalesce, inline,
-                                  topk_cut=cut)
+                                  exec_id, topk_cut=cut)
 
     shards = disp.map_shards(one_shard, bounds)
 

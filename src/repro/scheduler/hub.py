@@ -18,11 +18,12 @@ order), and per-tuple scores are independent of batch composition under
 the same documented condition the threads dispatcher already relies on
 (run_plan's docstring) — merging only regroups batches, exactly like
 coalescing across partitions does. Telemetry splits exactly: integer
-counters (kv_bytes, donated_bytes) are apportioned by segment size with
-the remainder on the leading segments so per-query stats tile the merged
-totals bit-for-bat even though a merged load cannot be re-measured per
-query; wall_s is apportioned proportionally (each query reports its
-share of the merged call's wall time).
+counters (kv_bytes and the integer flush counters) are apportioned by
+segment size with the remainder on the leading segments so per-query
+stats tile the merged totals bit-for-bit even though a merged load cannot
+be re-measured per query; wall_s and the flush counters' seconds are
+apportioned proportionally (each query reports its share of the merged
+call's time).
 
 Deadlock-freedom: quiescence is detected as ``blocked >= active`` with
 no fired group still executing; a driver doing long non-flush work
@@ -39,6 +40,7 @@ import time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from repro.obs import FlushCounters
 from repro.runtime.backend import Backend
 from repro.runtime.dispatch import FlushTask
 from repro.runtime.executor import _OperatorOutcome, run_operator
@@ -56,6 +58,21 @@ def split_ints(total: int, sizes: List[int]) -> List[int]:
     for i in range(rem):
         out[i] += 1
     return out
+
+
+def split_counters(total: FlushCounters, sizes: List[int]
+                   ) -> List[FlushCounters]:
+    """Apportion a merged call's FlushCounters over its segments: seconds
+    by tuple share, integers with `split_ints`, so the parts sum back to
+    the total (integers exactly)."""
+    n = max(sum(sizes), 1)
+    parts = [FlushCounters() for _ in sizes]
+    for name, value in total.as_dict().items():
+        shares = split_ints(value, sizes) if isinstance(value, int) \
+            else [value * s / n for s in sizes]
+        for part, v in zip(parts, shares):
+            setattr(part, name, v)
+    return parts
 
 
 class _PendingFlush:
@@ -327,12 +344,16 @@ class FlushHub:
                 lo = len(items)
                 items.extend(f.task.items)
                 segs.append((f, lo, len(items)))
-            out = run_operator(self._backend, sem_op, op_name, items)
+            execs = sorted({f.task.exec_id for f in members
+                            if f.task.exec_id is not None})
+            out = run_operator(
+                self._backend, sem_op, op_name, items,
+                exec_id=",".join(map(str, execs)) if execs else None)
             n_total = len(items)
             sizes = [hi - lo for _, lo, hi in segs]
             n_queries = len({id(f.ticket) for f in members})
             kv = split_ints(out.kv_bytes, sizes)
-            donated = split_ints(out.donated_bytes, sizes)
+            flush = split_counters(out.flush, sizes)
             for i, (f, lo, hi) in enumerate(segs):
                 frac = sizes[i] / max(n_total, 1)
                 f.outcome = _OperatorOutcome(
@@ -342,8 +363,7 @@ class FlushHub:
                     wall_s=out.wall_s * frac,
                     kv_bytes=kv[i],
                     uses_llm=out.uses_llm,
-                    h2d_overlap_s=out.h2d_overlap_s * frac,
-                    donated_bytes=donated[i],
+                    flush=flush[i],
                     merged_width=n_total if n_queries > 1 else 0,
                     merged_queries=n_queries)
         except BaseException as e:

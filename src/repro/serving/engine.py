@@ -26,14 +26,21 @@ The decode path is the Pallas fast path:
 Transfers overlap compute (`async_h2d` ctor arg, else STRETTO_ASYNC_H2D):
 a multi-batch run_filter/run_map dispatches the decode for batch i and
 loads + H2D-copies batch i+1's KV caches *before* forcing batch i's
-logits, so the transfer hides behind the accelerator's decode — the
-hidden time is counted into the `h2d_overlap_s` telemetry. On the same
-flag (and only when the device-resident LRU is off, which would need the
-buffers again) the jitted decode donates the consumed cache buffers back
-to XLA via donate_argnums, so the next batch's caches can reuse that HBM
-instead of peaking at 2x; donated bytes are counted into
-`donated_bytes`. Both counters are kept globally and per thread
-(`transfer_stats_local`), so the runtime's per-flush StageStats deltas
+logits, so the transfer can hide behind the accelerator's decode; the
+host time of that load is counted into `prefetch_s` (an upper bound on
+the time hidden: only a device trace shows what the device actually
+covered). On the same flag (and only when the device-resident LRU is
+off, which would need the buffers again) the jitted decode donates the
+consumed cache buffers back to XLA via donate_argnums, so the next
+batch's caches can reuse that HBM instead of peaking at 2x; donated
+bytes are counted into `donated_bytes`.
+
+Telemetry: every flush runs inside `stretto.engine.*` spans (flush, load,
+dispatch, prefetch, wait; `CacheStore.load_batch` adds read, assemble and
+h2d) and adds to the calling thread's `FlushCounters`
+(`transfer_stats_local`): read, assemble, host-to-device and wait
+seconds, prefetch seconds, XLA compiles, padded and donated bytes. The
+counters are per thread, so the runtime's per-flush StageStats deltas
 stay exact under concurrent dispatch.
 
 Multi-device placement: `place_on(device)` pins the calling thread's
@@ -48,10 +55,10 @@ larger batches -> fewer calls (the paper's batching speedup mechanism).
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import math
 import os
 import threading
-import time
 import warnings
 from collections import OrderedDict
 from dataclasses import dataclass, field
@@ -68,6 +75,7 @@ import numpy as np
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
 
+from repro import obs
 from repro.cache.compression import (QueryStats, calibrate_query_stats,
                                      compress_item_cache, quantize_kv)
 from repro.cache.store import CacheStore, Profile
@@ -130,14 +138,10 @@ class ServingEngine:
         # device_put params pytree
         self._placed_params: Dict[Tuple[str, Any], Any] = {}
         self._placed_lock = threading.Lock()
-        # transfer telemetry: H2D time hidden behind decode + donated KV
-        # bytes — global totals and per-thread counters (the runtime's
-        # StageStats deltas read the thread-local pair, so overlapping
-        # flushes never interleave into each other's deltas)
-        self.h2d_overlap_s = 0.0
-        self.donated_bytes = 0
-        self._xfer_lock = threading.Lock()
-        self._xfer_tl = threading.local()
+        # per-thread FlushCounters: the runtime's StageStats deltas read
+        # the calling thread's set, so overlapping flushes never
+        # interleave into each other's deltas
+        self._flush_tl = threading.local()
         # device-resident profile cache: (profile.tag, ids, headroom,
         # device id) -> (cache pytree on that device, nbytes); the device
         # is part of the key so a batch placed on one device is never
@@ -199,19 +203,30 @@ class ServingEngine:
                 self._placed_params[key] = got
             return got
 
-    def _count_xfer(self, h2d_s: float = 0.0, donated: int = 0):
-        tl = self._xfer_tl
-        tl.h2d_s = getattr(tl, "h2d_s", 0.0) + h2d_s
-        tl.donated = getattr(tl, "donated", 0) + donated
-        with self._xfer_lock:
-            self.h2d_overlap_s += h2d_s
-            self.donated_bytes += donated
+    def _counters(self) -> obs.FlushCounters:
+        """The calling thread's live counter set (mutated in place)."""
+        tl = self._flush_tl
+        if not hasattr(tl, "counters"):
+            tl.counters = obs.FlushCounters()
+        return tl.counters
 
-    def transfer_stats_local(self) -> Tuple[float, int]:
-        """Monotonic (h2d_overlap_s, donated_bytes) for the calling
-        thread — the runtime's run_operator takes before/after deltas."""
-        tl = self._xfer_tl
-        return (getattr(tl, "h2d_s", 0.0), getattr(tl, "donated", 0))
+    def transfer_stats_local(self) -> obs.FlushCounters:
+        """A snapshot of the calling thread's monotonic FlushCounters —
+        the runtime's run_operator takes before/after deltas."""
+        return dataclasses.replace(self._counters())
+
+    @contextlib.contextmanager
+    def _counting_compiles(self):
+        """Add the XLA compiles made on this thread inside the block to
+        its counters."""
+        n0, s0 = obs.compiles_here()
+        try:
+            yield
+        finally:
+            n1, s1 = obs.compiles_here()
+            c = self._counters()
+            c.compiles += n1 - n0
+            c.compile_s += s1 - s0
 
     # ---------------- offline phase ----------------
 
@@ -227,6 +242,11 @@ class ServingEngine:
         given ratio and then quantized to int8 with per-token scales
         (halved HBM traffic at decode time), stored under a distinct
         quant profile tag.
+
+        Each step runs in its span: `stretto.offline.prefill` (the
+        calibration prefill too), `stretto.offline.compress` (one ratio of
+        one item), `stretto.offline.quantize` (one int8 rung of one item:
+        compression and quantization) and `stretto.offline.save`.
         """
         em = self.models[model_name]
         cfg = em.cfg
@@ -237,36 +257,49 @@ class ServingEngine:
                 f"attn_kind={cfg.attn_kind!r} has none")
         # calibration on the first few items
         if has_cache and em.stats is None:
-            calib = _pad_tokens([it.tokens for it in items[:8]])
-            em.stats = calibrate_query_stats(em.params, cfg, tokens=calib)
+            with obs.span("stretto.offline.prefill", op=model_name,
+                          calibrate=1):
+                calib = _pad_tokens([it.tokens for it in items[:8]])
+                em.stats = calibrate_query_stats(em.params, cfg,
+                                                 tokens=calib)
+                jax.block_until_ready(em.stats)
         for start in range(0, len(items), prefill_batch):
             chunk = items[start:start + prefill_batch]
-            toks = _pad_tokens([it.tokens for it in chunk])
-            lengths = jnp.asarray([len(it.tokens) for it in chunk],
-                                  jnp.int32)
-            _, cache = prefill(em.params, cfg, tokens=toks,
-                               max_len=toks.shape[1], lengths=lengths)
+            with obs.span("stretto.offline.prefill", op=model_name,
+                          items=len(chunk)):
+                toks = _pad_tokens([it.tokens for it in chunk])
+                lengths = jnp.asarray([len(it.tokens) for it in chunk],
+                                      jnp.int32)
+                _, cache = prefill(em.params, cfg, tokens=toks,
+                                   max_len=toks.shape[1], lengths=lengths)
+                jax.block_until_ready(cache)
             for bi, it in enumerate(chunk):
                 item_cache = jax.tree.map(_take_item(bi), cache)
                 n = int(lengths[bi])
                 for ratio in ratios:
                     if not has_cache and ratio > 0:
                         continue     # rwkv6: no ladder (DESIGN.md)
-                    if has_cache:
+                    with obs.span("stretto.offline.compress", op=model_name,
+                                  ratio=ratio):
+                        if has_cache:
+                            arrays, new_len = compress_item_cache(
+                                cfg, item_cache, em.stats, ratio, n)
+                        else:
+                            arrays = {k: np.asarray(v[:, 0]) for k, v in
+                                      item_cache.items() if k != "lengths"}
+                            new_len = n
+                    with obs.span("stretto.offline.save", op=model_name):
+                        self.store.save(Profile(model_name, ratio),
+                                        it.item_id, arrays, new_len)
+                for ratio in quant_ratios:
+                    with obs.span("stretto.offline.quantize", op=model_name,
+                                  ratio=ratio):
                         arrays, new_len = compress_item_cache(
                             cfg, item_cache, em.stats, ratio, n)
-                    else:
-                        arrays = {k: np.asarray(v[:, 0]) for k, v in
-                                  item_cache.items() if k != "lengths"}
-                        new_len = n
-                    self.store.save(Profile(model_name, ratio), it.item_id,
-                                    arrays, new_len)
-                for ratio in quant_ratios:
-                    arrays, new_len = compress_item_cache(
-                        cfg, item_cache, em.stats, ratio, n)
-                    self.store.save(Profile(model_name, ratio, quant=True),
-                                    it.item_id, quantize_kv(arrays),
-                                    new_len)
+                        arrays = quantize_kv(arrays)
+                    with obs.span("stretto.offline.save", op=model_name):
+                        self.store.save(Profile(model_name, ratio, quant=True),
+                                        it.item_id, arrays, new_len)
 
     # ---------------- online phase ----------------
 
@@ -301,11 +334,14 @@ class ServingEngine:
         if key not in self._decode_jit:
             em = self.models[model_name]
 
+            # the flush program's ops carry the scope "stretto.flush" in
+            # their metadata (and XLA names some instructions after it)
             if fused:
                 def run_tokens(params, cache, tokens):
                     """All query tokens in ONE fused attention dispatch."""
-                    return decode_multi(params, em.cfg, cache,
-                                        tokens=tokens, kernels=backend)
+                    with jax.named_scope("stretto.flush"):
+                        return decode_multi(params, em.cfg, cache,
+                                            tokens=tokens, kernels=backend)
             else:
                 def run_tokens(params, cache, tokens):
                     """Feed tokens (B, L) sequentially; return final
@@ -315,8 +351,9 @@ class ServingEngine:
                                                     tokens=tok[:, None],
                                                     kernels=backend)
                         return cache, logits
-                    cache, logits_seq = jax.lax.scan(
-                        step, cache, jnp.moveaxis(tokens, 1, 0))
+                    with jax.named_scope("stretto.flush"):
+                        cache, logits_seq = jax.lax.scan(
+                            step, cache, jnp.moveaxis(tokens, 1, 0))
                     return logits_seq[-1], cache
 
             # donate the consumed cache buffers (arg 1) so XLA reuses
@@ -397,11 +434,17 @@ class ServingEngine:
                      ids: Sequence[int], headroom: int, n_real: int):
         """load_batch through the device-resident LRU (kv_bytes counts
         real loads only — a hit skips the npz-reload + re-pad + H2D copy
-        entirely)."""
+        entirely), in the span `stretto.engine.load`."""
+        with obs.span("stretto.engine.load", tuples=n_real):
+            return self._lookup_or_load(em, profile, ids, headroom, n_real)
+
+    def _lookup_or_load(self, em: EngineModel, profile: Profile,
+                        ids: Sequence[int], headroom: int, n_real: int):
         if not self.device_cache:
             cache, _ = self.store.load_batch(
                 em.cfg, profile, ids, pad_to_multiple=KERNEL_BLOCK_S,
-                headroom=headroom, n_real=n_real)
+                headroom=headroom, n_real=n_real,
+                counters=self._counters())
             return cache
         placement = self._placement()
         dev = None if placement is None else getattr(placement[0], "id",
@@ -416,7 +459,8 @@ class ServingEngine:
             self.dev_cache_misses += 1
             cache, _ = self.store.load_batch(
                 em.cfg, profile, ids, pad_to_multiple=KERNEL_BLOCK_S,
-                headroom=headroom, n_real=n_real)
+                headroom=headroom, n_real=n_real,
+                counters=self._counters())
             nbytes = sum(np.asarray(v).nbytes if not hasattr(v, "nbytes")
                          else v.nbytes for v in cache.values())
             self._dev_cache[key] = (cache, nbytes)
@@ -477,23 +521,28 @@ class ServingEngine:
         """One decode flush: load (or device-cache-hit, or take the
         prefetched) caches, run the query, return logits (len(ids) rows,
         NOT yet forced to host — callers np.asarray when they consume,
-        which is what lets the next batch's H2D hide behind the decode)."""
+        which is what lets the next batch's H2D hide behind the decode).
+        Runs in the span `stretto.engine.flush`, the load in
+        `stretto.engine.load` and the jitted call in
+        `stretto.engine.dispatch`."""
         pad = max(0, min(_bucket(len(ids)), bs) - len(ids))
         fn, fused, donate = self._flush_fn(profile.model_name)
         placement = self._placement()
-        with self._device_ctx(placement):
+        with obs.span("stretto.engine.flush", tuples=len(ids)), \
+                self._device_ctx(placement):
             cache = preloaded if preloaded is not None else \
                 self._load_for(em, profile, ids, query_tokens, bs)
             donated = sum(v.nbytes for v in cache.values()
                           if hasattr(v, "nbytes")) if donate else 0
-            params = self._params_for(em, profile.model_name, placement)
-            q = jnp.asarray([list(query_tokens)] * (len(ids) + pad),
-                            jnp.int32)
-            logits, _ = fn(params, cache, q)
-        if donated:
-            self._count_xfer(donated=donated)
+            with obs.span("stretto.engine.dispatch"):
+                params = self._params_for(em, profile.model_name, placement)
+                q = jnp.asarray([list(query_tokens)] * (len(ids) + pad),
+                                jnp.int32)
+                logits, _ = fn(params, cache, q)
+                logits = logits[:len(ids)]
+        self._counters().donated_bytes += donated
         self.attn_dispatches += 1 if fused else len(query_tokens)
-        return logits[:len(ids)]
+        return logits
 
     def _iter_flushes(self, em: EngineModel, profile: Profile,
                       item_ids: Sequence[int], query_tokens: Sequence[int],
@@ -501,9 +550,10 @@ class ServingEngine:
         """Yield (start, ids, logits) per flush batch. With `async_h2d`
         and more than one batch, batch i+1's caches are loaded (npz read
         + pad + H2D copy) right after batch i's decode is *dispatched*
-        and before its logits are forced — the consumer's np.asarray
-        blocks on the decode while the transfer proceeds, so the load
-        time counted into h2d_overlap_s is hidden from wall_s."""
+        and before its logits are forced, in the span
+        `stretto.engine.prefetch`: the consumer's np.asarray then blocks
+        on the decode while the transfer proceeds. The load's read,
+        assemble and h2d seconds are also counted into `prefetch_s`."""
         batches = [(s, list(item_ids[s:s + bs]))
                    for s in range(0, len(item_ids), bs)]
         prefetch = self.async_h2d and len(batches) > 1
@@ -514,12 +564,13 @@ class ServingEngine:
             pre = None
             if prefetch and bi + 1 < len(batches):
                 nxt = batches[bi + 1][1]
-                t0 = time.perf_counter()
-                with self._device_ctx(self._placement()):
+                c = self._counters()
+                before = c.read_s + c.assemble_s + c.h2d_s
+                with obs.span("stretto.engine.prefetch"), \
+                        self._device_ctx(self._placement()):
                     pre = self._load_for(em, profile, nxt, query_tokens, bs)
-                self._count_xfer(h2d_s=time.perf_counter() - t0)
+                c.prefetch_s += c.read_s + c.assemble_s + c.h2d_s - before
             yield s, ids, logits
-
     def run_filter(self, model_name: str, profile_ratio: float,
                    item_ids: Sequence[int], query_tokens: Sequence[int],
                    yes_token: int, no_token: int,
@@ -529,11 +580,13 @@ class ServingEngine:
         profile = Profile(model_name, profile_ratio, quant)
         out = np.zeros(len(item_ids), np.float32)
         bs = self._batch_size(profile, item_ids)
-        for s, ids, logits in self._iter_flushes(em, profile, item_ids,
-                                                 query_tokens, bs):
-            lo = np.asarray(logits[:, yes_token] - logits[:, no_token],
-                            np.float32)
-            out[s:s + len(ids)] = lo
+        with self._counting_compiles():
+            for s, ids, logits in self._iter_flushes(em, profile, item_ids,
+                                                     query_tokens, bs):
+                lo = logits[:, yes_token] - logits[:, no_token]
+                with obs.span("stretto.engine.wait") as wait:
+                    out[s:s + len(ids)] = np.asarray(lo, np.float32)
+                self._counters().wait_s += wait.secs
         return out
 
     def run_map(self, model_name: str, profile_ratio: float,
@@ -546,13 +599,18 @@ class ServingEngine:
         vals = np.zeros(len(item_ids), np.int64)
         confs = np.zeros(len(item_ids), np.float32)
         bs = self._batch_size(profile, item_ids)
-        vt = jnp.asarray(list(value_tokens))
-        for s, ids, logits in self._iter_flushes(em, profile, item_ids,
-                                                 query_tokens, bs):
-            vlogits = logits[:, vt]                        # (B, n_vals)
-            top2 = jax.lax.top_k(vlogits, 2)[0]
-            vals[s:s + len(ids)] = np.asarray(vt[jnp.argmax(vlogits, -1)])
-            confs[s:s + len(ids)] = np.asarray(top2[:, 0] - top2[:, 1])
+        with self._counting_compiles():
+            vt = jnp.asarray(list(value_tokens))
+            for s, ids, logits in self._iter_flushes(em, profile, item_ids,
+                                                     query_tokens, bs):
+                vlogits = logits[:, vt]                    # (B, n_vals)
+                top2 = jax.lax.top_k(vlogits, 2)[0]
+                best = vt[jnp.argmax(vlogits, -1)]
+                margin = top2[:, 0] - top2[:, 1]
+                with obs.span("stretto.engine.wait") as wait:
+                    vals[s:s + len(ids)] = np.asarray(best)
+                    confs[s:s + len(ids)] = np.asarray(margin)
+                self._counters().wait_s += wait.secs
         return vals, confs
 
 

@@ -9,6 +9,7 @@ import pytest
 from repro.api import Session, SessionConfig
 from repro.cache.store import CacheStore, Profile
 from repro.core import PlannerConfig
+from repro.obs import FlushCounters
 from repro.data.synthetic import (TOK_NO, TOK_YES, filter_query_token,
                                   make_dataset, make_planted_params,
                                   planted_config)
@@ -342,10 +343,14 @@ def test_decisions_identical_across_kernel_backends(api_world, monkeypatch):
                                           err_msg=str(key))
         assert _stats_key(res) == _stats_key(base), key
     # EXPLAIN ANALYZE is identical apart from measured wall-clock columns
+    # and the flush line's host times, compiles and bytes (which follow
+    # the compile cache and the device LRU, not the kernels backend)
     import re
 
     def strip_times(text):
         text = re.sub(r"\d+\.\d+(ms|s|us)\b", "<t>", text)
+        text = re.sub(r"^flush: .*$", "flush: <measured>", text,
+                      flags=re.M)
         return re.sub(r"(runtime_s|wall_s)=\d+\.\d+", r"\1=<t>", text)
 
     rep_ref = strip_times(runs[("ref", "inline")].explain_analyze().render())
@@ -370,12 +375,13 @@ def test_session_config_validates_kernels_backend():
 # async H2D overlap + donation
 # ---------------------------------------------------------------------------
 
-def test_async_h2d_identical_results_and_counters(engine):
+def test_async_h2d_identical_results_and_prefetch_s(engine):
     """Transfer overlap is a schedule change, not a math change: with the
     memory budget forcing a multi-batch flush, async_h2d=True must return
-    bit-identical logits while the engine's h2d_overlap_s (prefetch time
-    hidden behind decode) and donated_bytes (consumed KV buffers handed
-    back to XLA) counters both advance."""
+    bit-identical logits while the calling thread's prefetch_s (host time
+    spent loading the next batch before the current result was forced)
+    and donated_bytes (consumed KV buffers handed back to XLA) counters
+    both advance."""
     eng, ds = engine
     ids = _ids(ds, 24)
     query = [filter_query_token(1)]
@@ -388,19 +394,20 @@ def test_async_h2d_identical_results_and_counters(engine):
         eng.async_h2d = False
         base = eng.run_filter("sm", 0.0, ids, query, TOK_YES, TOK_NO)
         eng.async_h2d = True
-        h0, d0 = eng.h2d_overlap_s, eng.donated_bytes
+        c0 = eng.transfer_stats_local()
         overlapped = eng.run_filter("sm", 0.0, ids, query, TOK_YES, TOK_NO)
+        d = eng.transfer_stats_local() - c0
         np.testing.assert_array_equal(overlapped, base)
-        assert eng.h2d_overlap_s > h0              # prefetches were timed
-        assert eng.donated_bytes > d0              # consumed KV donated
+        assert d.prefetch_s > 0                    # prefetches were timed
+        assert d.donated_bytes > 0                 # consumed KV donated
     finally:
         eng.async_h2d = flag0
         eng.memory_budget = budget0
 
 
-def test_async_h2d_single_batch_no_prefetch(engine):
+def test_async_h2d_single_batch_no_prefetch_s(engine):
     """A corpus that fits one flush batch has no 'next cohort' to stage:
-    h2d_overlap_s must not move (nothing was overlapped), while donation
+    prefetch_s must not move (nothing was prefetched), while donation
     still returns the one consumed cache buffer."""
     eng, ds = engine
     ids = _ids(ds, 6)
@@ -408,10 +415,11 @@ def test_async_h2d_single_batch_no_prefetch(engine):
     flag0 = eng.async_h2d
     try:
         eng.async_h2d = True
-        h0, d0 = eng.h2d_overlap_s, eng.donated_bytes
+        c0 = eng.transfer_stats_local()
         eng.run_filter("sm", 0.0, ids, query, TOK_YES, TOK_NO)
-        assert eng.h2d_overlap_s == h0
-        assert eng.donated_bytes > d0
+        d = eng.transfer_stats_local() - c0
+        assert d.prefetch_s == 0
+        assert d.donated_bytes > 0
     finally:
         eng.async_h2d = flag0
 
@@ -429,11 +437,11 @@ def test_donation_disabled_with_device_cache(engine):
         eng.async_h2d = True
         eng.device_cache = True
         eng.device_cache_clear()
-        d0 = eng.donated_bytes
+        d0 = eng.transfer_stats_local().donated_bytes
         first = eng.run_filter("sm", 0.0, ids, query, TOK_YES, TOK_NO)
         again = eng.run_filter("sm", 0.0, ids, query, TOK_YES, TOK_NO)
         np.testing.assert_array_equal(first, again)
-        assert eng.donated_bytes == d0             # never donated
+        assert eng.transfer_stats_local().donated_bytes == d0  # never
     finally:
         eng.async_h2d = flag0
         eng.device_cache = False
@@ -441,7 +449,7 @@ def test_donation_disabled_with_device_cache(engine):
 
 
 def test_transfer_stats_thread_local(engine):
-    """The executor attributes h2d/donation deltas to the flush that
+    """The executor attributes flush-counter deltas to the flush that
     caused them via thread-scoped counters (a flush runs entirely on one
     dispatcher thread) — an async run must advance the calling thread's
     transfer_stats_local, and a fresh thread must start at zero."""
@@ -456,13 +464,13 @@ def test_transfer_stats_thread_local(engine):
         t0 = eng.transfer_stats_local()
         eng.run_filter("sm", 0.0, ids, query, TOK_YES, TOK_NO)
         t1 = eng.transfer_stats_local()
-        assert t1[1] > t0[1]                       # this thread donated
+        assert t1.donated_bytes > t0.donated_bytes  # this thread donated
         seen = {}
         th = threading.Thread(
             target=lambda: seen.update(other=eng.transfer_stats_local()))
         th.start()
         th.join()
-        assert seen["other"] == (0.0, 0)           # not leaked cross-thread
+        assert seen["other"] == FlushCounters()    # not leaked cross-thread
     finally:
         eng.async_h2d = flag0
 
@@ -475,3 +483,95 @@ def test_engine_loads_padded_to_kernel_block(engine):
         eng.models["sm"].cfg, Profile("sm", 0.0), _ids(ds, 3),
         pad_to_multiple=KERNEL_BLOCK_S, headroom=4, n_real=3)
     assert cache["k"].shape[2] % KERNEL_BLOCK_S == 0
+
+
+# ---------------------------------------------------------------------------
+# flush counters and spans
+# ---------------------------------------------------------------------------
+
+def test_two_batch_flush_counts_each_step(engine, monkeypatch):
+    """A two-batch async run_filter spends host time in every step of a
+    flush — shard reads, np.pad/np.stack, the copy to the device, the wait
+    for the result — and prefetches the second batch while the first is
+    in flight; padded_bytes is exactly the bytes of the padded caches
+    load_batch returned (its lengths vector aside)."""
+    eng, ds = engine
+    per_item = eng.store.item_nbytes(Profile("sm", 0.0))
+    returned = []
+    orig = eng.store.load_batch
+
+    def spy(*a, **kw):
+        cache, lengths = orig(*a, **kw)
+        returned.append(cache)
+        return cache, lengths
+
+    monkeypatch.setattr(eng.store, "load_batch", spy)
+    budget0, flag0 = eng.memory_budget, eng.async_h2d
+    try:
+        eng.memory_budget = 8 * per_item           # two flush batches
+        eng.async_h2d = True
+        c0 = eng.transfer_stats_local()
+        eng.run_filter("sm", 0.0, _ids(ds, 16), [filter_query_token(4)],
+                       TOK_YES, TOK_NO)
+        d = eng.transfer_stats_local() - c0
+    finally:
+        eng.async_h2d = flag0
+        eng.memory_budget = budget0
+    assert len(returned) == 2
+    for name in ("read_s", "assemble_s", "h2d_s", "wait_s", "prefetch_s"):
+        assert getattr(d, name) > 0, name
+    assert d.prefetch_s <= d.read_s + d.assemble_s + d.h2d_s
+    assert d.padded_bytes == sum(int(v.nbytes) for cache in returned
+                                 for k, v in cache.items()
+                                 if k != "lengths")
+
+
+def test_compiles_counted_on_a_new_shape_only(engine):
+    """The first flush of a shape the engine has not run compiles its
+    programs on the calling thread and counts them; the same flush again
+    compiles nothing."""
+    eng, ds = engine
+    ids = _ids(ds, 5)
+    query = [filter_query_token(5)] * 3            # a query length no
+    #                                                other test flushes
+    c0 = eng.transfer_stats_local()
+    eng.run_filter("sm", 0.0, ids, query, TOK_YES, TOK_NO)
+    first = eng.transfer_stats_local() - c0
+    eng.run_filter("sm", 0.0, ids, query, TOK_YES, TOK_NO)
+    again = eng.transfer_stats_local() - c0 - first
+    assert first.compiles >= 1 and first.compile_s > 0
+    assert again.compiles == 0 and again.compile_s == 0
+
+
+def test_flush_spans_nest_in_the_profiler_trace(engine, tmp_path):
+    """The engine's spans land in the profiler's own trace: a flush holds
+    its batch assembly, and both carry the execution id and operator of
+    the runtime span they run in."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro import obs
+
+    eng, ds = engine
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("stretto.run.flush", exec=7, op="probe"):
+            eng.run_filter("sm", 0.0, _ids(ds, 4), [filter_query_token(6)],
+                           TOK_YES, TOK_NO)
+    events = {}
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("stretto."):
+                    events.setdefault(ev.name, []).append(
+                        (plane.name, line.name, ev.start_ns,
+                         ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    flushes = events["stretto.engine.flush"]
+    assembles = events["stretto.store.assemble"]
+    assert any(f[:2] == a[:2] and f[2] <= a[2] and a[3] <= f[3]
+               for f in flushes for a in assembles)
+    for name in ("stretto.engine.flush", "stretto.store.assemble"):
+        for *_, stats in events[name]:
+            assert stats["exec"] == 7 and stats["op"] == "probe", name

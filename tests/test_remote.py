@@ -308,6 +308,8 @@ def test_every_fast_operator_scores_bit_identically(sessions):
     ds, local, remote = sessions
     op = SemFilter("f1", 1)
     batch = ds.items[:32]
+    member, = remote_members(remote.backend)
+    f0 = member.transfer_stats()
     for cand in local.backend.candidates(op):
         ls = local.backend.score_filter(op, cand.name, batch)
         rs = remote.backend.score_filter(op, cand.name, batch)
@@ -319,6 +321,8 @@ def test_every_fast_operator_scores_bit_identically(sessions):
         rv, rcf = remote.backend.run_map(mop, cand.name, batch)
         np.testing.assert_array_equal(rv, lv)
         np.testing.assert_array_equal(rcf, lcf)
+    # the worker engine's flush counters cross the wire to this thread
+    assert (member.transfer_stats() - f0).wait_s > 0
 
 
 @pytest.mark.parametrize("dispatcher", ["inline", "threads:2"])

@@ -32,6 +32,8 @@ from repro.data.synthetic import make_dataset
 from repro.runtime import OracleBackend, backend_engines
 from repro.scheduler import (QueryScheduler, SchedulerSaturated, TenantSpec,
                              split_ints, validate_tenants)
+from repro.obs import FlushCounters
+from repro.scheduler.hub import split_counters
 
 FAST = PlannerConfig(steps=120, restarts=2, snapshots=2)
 # scheduler tests exercise admission/coalescing, not plan quality — a
@@ -68,6 +70,23 @@ def test_split_ints_tiles_exactly():
         assert sum(out) == (total if sizes and sum(sizes) else 0)
         assert len(out) == len(sizes)
         assert all(v >= 0 for v in out)
+
+
+def test_split_counters_tiles_exactly():
+    """A merged call's flush counters split per query: integers exactly
+    (split_ints), seconds by tuple share, and the parts sum back."""
+    total = FlushCounters(read_s=0.3, assemble_s=1.2, h2d_s=0.9, wait_s=2.1,
+                          prefetch_s=0.6, compile_s=0.5, compiles=3,
+                          padded_bytes=1003, donated_bytes=97)
+    sizes = [37, 1, 0, 256]
+    parts = split_counters(total, sizes)
+    assert [p.padded_bytes for p in parts] == split_ints(1003, sizes)
+    assert sum(p.compiles for p in parts) == 3
+    summed = sum(parts, FlushCounters())
+    for name, value in total.as_dict().items():
+        assert getattr(summed, name) == pytest.approx(value, rel=1e-12)
+    assert parts[0].wait_s == pytest.approx(2.1 * 37 / 294)
+    assert parts[2] == FlushCounters()
 
 
 def test_session_config_validates_tenants():

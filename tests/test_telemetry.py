@@ -8,6 +8,7 @@ assert on; engine-backed KV-bytes parity lives in tests/test_api.py where
 the profile-built session fixture already exists.
 """
 import json
+import threading
 import time
 
 import numpy as np
@@ -18,6 +19,7 @@ from repro.core import MeasuredBatchStore, PlannerConfig, Query, \
     SemFilter, SemMap, batch_drift
 from repro.core.physical import (PhysicalOperator, PhysicalPlan,
                                  PhysicalPlanStage)
+from repro.obs import FlushCounters
 from repro.runtime import OracleBackend, as_backend, iter_plan, run_plan
 from repro.runtime.executor import StageStats, merge_stage_stats
 
@@ -102,16 +104,49 @@ def _stats_by_key(stats):
     return {(s.logical_idx, s.stage, s.op_name): s for s in stats}
 
 
+class _CountingBackend(OracleBackend):
+    """The oracle operators plus a per-thread FlushCounters set that grows
+    with the tuples each flush scores, as a serving engine's does."""
+
+    PER_TUPLE = FlushCounters(read_s=1e-4, assemble_s=3e-3, h2d_s=2e-3,
+                              wait_s=5e-3, prefetch_s=1e-3, compile_s=7e-4,
+                              compiles=1, padded_bytes=96, donated_bytes=64)
+
+    def __init__(self, registry):
+        super().__init__(registry)
+        self._tl = threading.local()
+
+    def _count(self, n):
+        per = self.PER_TUPLE.as_dict()
+        self._tl.c = self.transfer_stats() + FlushCounters(
+            **{k: v * n for k, v in per.items()})
+
+    def transfer_stats(self):
+        return getattr(self._tl, "c", FlushCounters())
+
+    def score_filter(self, op, op_name, items):
+        self._count(len(items))
+        return super().score_filter(op, op_name, items)
+
+    def run_map(self, op, op_name, items):
+        self._count(len(items))
+        return super().run_map(op, op_name, items)
+
+
+BACKENDS = {"oracle": as_backend, "flush-counters": _CountingBackend}
+
+
 # ---------------------------------------------------------------------------
 # per-partition StageStats tile the run's final stats exactly
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("backend", sorted(BACKENDS))
 @pytest.mark.parametrize("dispatcher", ["inline", "threads:3", "sharded:3"])
 @pytest.mark.parametrize("part", [7, 20, None])
-def test_partition_stats_sum_to_final(dispatcher, part):
+def test_partition_stats_sum_to_final(dispatcher, part, backend):
     items = [_Item(i) for i in range(53)]
     q, plan, registry = _world()
-    gen = iter_plan(plan, q, items, as_backend(registry),
+    gen = iter_plan(plan, q, items, BACKENDS[backend](registry),
                     partition_size=part, coalesce=13, dispatcher=dispatcher)
     parts = []
     while True:
@@ -133,12 +168,26 @@ def test_partition_stats_sum_to_final(dispatcher, part):
         assert m.n_batches == sg.n_batches, key
         assert m.kv_bytes == sg.kv_bytes, key
         assert m.wall_s == pytest.approx(sg.wall_s, rel=1e-9), key
+        # the flush counter set tiles the same way: integers exactly,
+        # seconds up to summation order
+        for name, value in sg.flush.as_dict().items():
+            got = getattr(m.flush, name)
+            if isinstance(value, int):
+                assert got == value, (key, name)
+            else:
+                assert got == pytest.approx(value, rel=1e-9), (key, name)
     # and the counts themselves are real: every corpus tuple was scored
     # by the first stage exactly once
-    assert fin[(0, 0, "f-cheap")].n_tuples == len(items)
+    first = fin[(0, 0, "f-cheap")]
+    assert first.n_tuples == len(items)
+    per = _CountingBackend.PER_TUPLE if backend == "flush-counters" \
+        else FlushCounters()
+    assert first.flush.padded_bytes == per.padded_bytes * len(items)
+    assert first.flush.wait_s == pytest.approx(per.wait_s * len(items))
 
 
-def test_final_stage_counters_bit_identical_across_dispatchers():
+@pytest.mark.parametrize("backend", sorted(BACKENDS))
+def test_final_stage_counters_bit_identical_across_dispatchers(backend):
     """The *final* integer counters are dispatcher-invariant: every stage
     scores exactly the same tuple set under any dispatcher (the flush
     membership invariant), so n_tuples / n_llm_calls / kv_bytes must be
@@ -149,7 +198,7 @@ def test_final_stage_counters_bit_identical_across_dispatchers():
 
     def run(disp):
         q, plan, registry = _world()
-        return run_plan(plan, q, items, as_backend(registry),
+        return run_plan(plan, q, items, BACKENDS[backend](registry),
                         partition_size=9, coalesce=11, dispatcher=disp)
 
     ref = _stats_by_key(run("inline").stage_stats)
@@ -160,6 +209,10 @@ def test_final_stage_counters_bit_identical_across_dispatchers():
             assert got[key].n_tuples == ref[key].n_tuples, (disp, key)
             assert got[key].n_llm_calls == ref[key].n_llm_calls, (disp, key)
             assert got[key].kv_bytes == ref[key].kv_bytes, (disp, key)
+            for name, value in ref[key].flush.as_dict().items():
+                if isinstance(value, int):
+                    assert getattr(got[key].flush, name) == value, \
+                        (disp, key, name)
 
 
 # ---------------------------------------------------------------------------
