@@ -341,7 +341,9 @@ class ExplainReport:
                     f"wait_s={f.wait_s:.3f} prefetch_s={f.prefetch_s:.3f} "
                     f"compiles={f.compiles} compile_s={f.compile_s:.3f} "
                     f"padded_MB={f.padded_bytes / 1e6:.1f} "
-                    f"donated_MB={f.donated_bytes / 1e6:.1f}")
+                    f"donated_MB={f.donated_bytes / 1e6:.1f} "
+                    f"staged_reuses={f.staged_reuses} "
+                    f"staged_allocs={f.staged_allocs}")
             if any(eng for eng, *_ in self.measured_engines):
                 for eng, wall, tuples, llm, kv in self.measured_engines:
                     out.append(

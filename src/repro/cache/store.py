@@ -5,7 +5,9 @@ quantization). The store holds one compressed cache per (profile, item)
 as an .npz shard, written once in the offline phase and memory-mapped at
 query time. `load_batch` re-pads a set of items to the max compressed
 length in the batch — the paper's batching scheme — and returns a
-decode-ready cache pytree.
+decode-ready cache pytree. It copies each item once, into its slot of a
+host staging buffer that the calling thread reuses from batch to batch
+(`_Staging`), and hands that buffer to the device.
 
 Alongside the shards, each profile directory carries an append-only
 `_meta.jsonl` recording per-item byte sizes at `save` time, so batch
@@ -15,8 +17,10 @@ decompressing a full .npz shard per flush.
 from __future__ import annotations
 
 import json
+import math
 import os
 import threading
+import weakref
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -28,6 +32,9 @@ from repro.configs.base import ModelConfig
 from repro.obs import FlushCounters
 
 META_FILE = "_meta.jsonl"
+# cache keys laid out (L, S', ...) per item: padded along S' to the batch's
+# S_max; every other key is stacked as it is
+SEQ_KEYS = frozenset({"k", "v", "c_kv", "k_rope", "k_scale", "v_scale"})
 
 
 @dataclass(frozen=True)
@@ -40,6 +47,72 @@ class Profile:
     def tag(self) -> str:
         base = f"{self.model_name}__r{int(round(self.ratio * 100)):02d}"
         return base + ("__q8" if self.quant else "")
+
+
+class _Staging:
+    """One reused host buffer that a batch of one cache key is assembled
+    in: flat bytes, viewed as the C-contiguous (L, B, S_max, ...) batch.
+
+    It grows to the largest batch it has held and never shrinks. Each item
+    is copied once into its slot through a same-width unsigned-integer
+    view, which numpy copies as plain memory whatever the dtype (bfloat16,
+    int8, float32). Pad positions read 0: while the batch's shape is
+    unchanged only `[n_i, high_i)` of slot i is zeroed, the part a longer
+    earlier item left; any other shape zeroes the whole pad.
+
+    `sent` is a weak reference to the device array last copied from the
+    buffer (weak, so that the buffer holds no device memory): an
+    asynchronous host-to-device copy reads the buffer until it lands, so
+    `wait` blocks on it before the buffer is written again. An
+    array that was donated to a computation is skipped: the caller forced
+    that computation, and so the copy, before loading into this buffer
+    again (the engine does, within two loads).
+    """
+
+    __slots__ = ("buf", "layout", "high", "sent")
+
+    def __init__(self):
+        self.buf = np.empty(0, np.uint8)
+        self.layout: Optional[Tuple] = None
+        self.high: List[int] = []
+        self.sent: Optional[weakref.ref] = None
+
+    def wait(self) -> None:
+        arr = self.sent() if self.sent is not None else None
+        if arr is not None and not arr.is_deleted():
+            arr.block_until_ready()
+
+    def fill(self, items: Sequence[np.ndarray],
+             smax: Optional[int]) -> Tuple[np.ndarray, bool]:
+        """The batch of `items`, padded along axis 1 to `smax` (None:
+        stacked unpadded), and whether the buffer had to grow."""
+        a0 = items[0]
+        shape = (a0.shape[0], len(items)) + (
+            a0.shape[1:] if smax is None else (smax,) + a0.shape[2:])
+        nbytes = math.prod(shape) * a0.dtype.itemsize
+        grew = nbytes > self.buf.nbytes
+        if grew:
+            self.buf = np.empty(nbytes, np.uint8)
+        layout = (shape, a0.dtype)
+        if grew or layout != self.layout:
+            self.high = [shape[2] if smax is not None else 0] * len(items)
+        # unset until every slot is written, so a fill that raises leaves
+        # the next one to zero the whole pad
+        self.layout = None
+        out = self.buf[:nbytes].view(a0.dtype).reshape(shape)
+        raw = out.view(f"u{a0.dtype.itemsize}")
+        for i, a in enumerate(items):
+            src = a.view(raw.dtype)
+            if smax is None:
+                raw[:, i] = src
+                continue
+            n = a.shape[1]
+            raw[:, i, :n] = src
+            if self.high[i] > n:
+                raw[:, i, n:self.high[i]] = 0
+            self.high[i] = n
+        self.layout = layout
+        return out, grew
 
 
 class CacheStore:
@@ -186,10 +259,20 @@ class CacheStore:
         an exact quantity independent of how flushes were grouped — not
         the padding replicas.
 
-        The steps run in the spans `stretto.store.read`, then per cache
-        key `stretto.store.assemble` (np.pad, np.stack) and
-        `stretto.store.h2d` (the copy to the device); with `counters`
-        given, their seconds and the bytes of the padded batch are added
+        Each cache key's batch is assembled in one of two host staging
+        buffers per key that the calling thread uses in turn (`_Staging`):
+        each item is copied once, into its slot. So the copy to the device
+        of one batch may still be in flight while the next is assembled;
+        before a buffer is written again, the copy made from it last has
+        to land. The returned arrays never alias a buffer.
+
+        The steps run in the spans `stretto.store.read`, then
+        `stretto.store.h2d` (waiting for the copies out of this turn's
+        buffers to land), then per cache key `stretto.store.assemble`
+        (filling its buffer) and `stretto.store.h2d` (the copy to the
+        device); with `counters` given, their seconds, the bytes of the
+        padded batch and whether the buffers had room for it
+        (`staged_reuses`) or one had to grow (`staged_allocs`) are added
         to it.
         """
         with obs.span("stretto.store.read") as read:
@@ -204,26 +287,31 @@ class CacheStore:
         smax = int(lengths.max()) + headroom
         smax = ((smax + pad_to_multiple - 1) // pad_to_multiple
                 * pad_to_multiple)
-        seq_keys = {"k", "v", "c_kv", "k_rope", "k_scale", "v_scale"}
+        keys = [k for k in shards[0] if k != "__length__"]
+        turn = getattr(self._tl, "turn", 0)
+        self._tl.turn = 1 - turn
+        staging = self._tl.__dict__.setdefault("staging", {})
+        bufs = [staging.setdefault(k, (_Staging(), _Staging()))[turn]
+                for k in keys]
+        with obs.span("stretto.store.h2d", key="staging") as landed:
+            for buf in bufs:
+                buf.wait()
         cache: Dict[str, Any] = {}
-        assemble_s = h2d_s = 0.0
-        for key in shards[0]:
-            if key == "__length__":
-                continue
+        assemble_s, h2d_s, grew = 0.0, landed.secs, False
+        for key, buf in zip(keys, bufs):
             with obs.span("stretto.store.assemble", key=key) as assemble:
-                per = []
-                for s in shards:
-                    a = s[key]
-                    if key in seq_keys:   # (L, S', ...) -> pad S' to smax
-                        pad = [(0, 0)] * a.ndim
-                        pad[1] = (0, smax - a.shape[1])
-                        a = np.pad(a, pad)
-                    per.append(a)
-                stacked = np.stack(per, axis=1)       # (L, B, ...)
+                batch, g = buf.fill([s[key] for s in shards],
+                                    smax if key in SEQ_KEYS else None)
             with obs.span("stretto.store.h2d", key=key) as h2d:
-                cache[key] = jnp.asarray(stacked)
+                cache[key] = jnp.asarray(batch)
+                if cache[key].device.platform == "cpu":
+                    # the CPU adopts an aligned host buffer as the array's
+                    # own memory: give it one that is never refilled
+                    cache[key] = jnp.asarray(batch.copy())
+            buf.sent = weakref.ref(cache[key])
             assemble_s += assemble.secs
             h2d_s += h2d.secs
+            grew |= g
         with obs.span("stretto.store.h2d", key="lengths") as h2d:
             cache["lengths"] = jnp.asarray(lengths)
         if counters is not None:
@@ -232,4 +320,6 @@ class CacheStore:
             counters.h2d_s += h2d_s + h2d.secs
             counters.padded_bytes += sum(int(v.nbytes) for k, v in
                                          cache.items() if k != "lengths")
+            counters.staged_allocs += int(grew)
+            counters.staged_reuses += int(not grew)
         return cache, lengths

@@ -94,9 +94,13 @@ class FlushCounters:
     """Where a flush's host time went, and what it moved. Times are host
     seconds of the matching `stretto.*` spans; counts are exact."""
     read_s: float = 0.0        # shard reads (stretto.store.read)
-    assemble_s: float = 0.0    # np.pad + np.stack (stretto.store.assemble)
+    assemble_s: float = 0.0    # copying each item into its slot of the
+    #                            reused staging buffers, zeroing the pad
+    #                            (stretto.store.assemble)
     h2d_s: float = 0.0         # jnp.asarray copies of the batch to the
-    #                            device (stretto.store.h2d)
+    #                            device, and waiting for the last copy out
+    #                            of a staging buffer to land before it is
+    #                            refilled (stretto.store.h2d)
     wait_s: float = 0.0        # blocked forcing a flush's result to the
     #                            host (stretto.engine.wait)
     prefetch_s: float = 0.0    # the part of read_s + assemble_s + h2d_s
@@ -108,6 +112,8 @@ class FlushCounters:
     compiles: int = 0
     padded_bytes: int = 0      # bytes of the padded batches built
     donated_bytes: int = 0     # consumed cache buffers donated to XLA
+    staged_reuses: int = 0     # batch loads the staging buffers had room for
+    staged_allocs: int = 0     # batch loads that allocated or grew one
 
     def __add__(self, other: "FlushCounters") -> "FlushCounters":
         return FlushCounters(**{f.name: getattr(self, f.name)

@@ -359,6 +359,24 @@ def test_decisions_identical_across_kernel_backends(api_world, monkeypatch):
     assert rep_ref == rep_int
 
 
+def test_explain_analyze_prints_staging_counters(api_world):
+    """EXPLAIN ANALYZE's flush line carries the staging counters summed
+    over the run's stage flushes: one per batch the engines loaded."""
+    import re
+
+    ds, sess = api_world
+    for eng in sess.engines.values():
+        eng.device_cache_clear()
+    res = sess.frame(ds.items).sem_filter("f1", 1).execute()
+    flush = [s.flush for s in res.stage_stats]
+    line, = re.findall(r"^flush: .*$", res.explain_analyze().render(),
+                       flags=re.M)
+    reuses = sum(f.staged_reuses for f in flush)
+    allocs = sum(f.staged_allocs for f in flush)
+    assert reuses + allocs > 0
+    assert line.endswith(f" staged_reuses={reuses} staged_allocs={allocs}")
+
+
 def test_session_config_validates_kernels_backend():
     """The kernels knob is part of the declarative config surface and is
     validated at construction, not first flush."""
@@ -491,10 +509,12 @@ def test_engine_loads_padded_to_kernel_block(engine):
 
 def test_two_batch_flush_counts_each_step(engine, monkeypatch):
     """A two-batch async run_filter spends host time in every step of a
-    flush — shard reads, np.pad/np.stack, the copy to the device, the wait
-    for the result — and prefetches the second batch while the first is
-    in flight; padded_bytes is exactly the bytes of the padded caches
-    load_batch returned (its lengths vector aside)."""
+    flush — shard reads, copying the items into the staging buffers, the
+    copy to the device, the wait for the result — and prefetches the
+    second batch while the first is in flight; padded_bytes is exactly
+    the bytes of the padded caches load_batch returned (its lengths
+    vector aside), and each load counts once as a staging reuse or
+    allocation."""
     eng, ds = engine
     per_item = eng.store.item_nbytes(Profile("sm", 0.0))
     returned = []
@@ -524,6 +544,45 @@ def test_two_batch_flush_counts_each_step(engine, monkeypatch):
     assert d.padded_bytes == sum(int(v.nbytes) for cache in returned
                                  for k, v in cache.items()
                                  if k != "lengths")
+    assert d.staged_reuses + d.staged_allocs == len(returned)
+
+
+@pytest.mark.parametrize("device_cache", [False, True],
+                         ids=["donated", "lru-held"])
+@pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
+def test_async_two_batch_filter_matches_sync(engine, device_cache, quant):
+    """The second batch is assembled in the other staging buffer while
+    the first batch's copy to the device may still be in flight: the
+    scores equal those of a synchronous run, whether the decode donates
+    the copied caches or the device LRU holds them, for the float and
+    the int8 profile."""
+    eng, ds = engine
+    ids = _ids(ds, 16)
+    ratio = 0.5 if quant else 0.0
+    query = [filter_query_token(7)]
+    per_item = eng.store.item_nbytes(Profile("sm", ratio, quant), ids[0])
+    budget0, flag0 = eng.memory_budget, eng.async_h2d
+    try:
+        eng.memory_budget = 8 * per_item           # two flush batches
+        eng.device_cache = device_cache
+        eng.async_h2d = False
+        base = eng.run_filter("sm", ratio, ids, query, TOK_YES, TOK_NO,
+                              quant=quant)
+        eng.device_cache_clear()
+        eng.async_h2d = True
+        c0 = eng.transfer_stats_local()
+        got = eng.run_filter("sm", ratio, ids, query, TOK_YES, TOK_NO,
+                             quant=quant)
+        d = eng.transfer_stats_local() - c0
+    finally:
+        eng.async_h2d = flag0
+        eng.memory_budget = budget0
+        eng.device_cache = False
+        eng.device_cache_clear()
+    np.testing.assert_array_equal(got, base)
+    assert d.prefetch_s > 0
+    assert d.staged_reuses + d.staged_allocs == 2
+    assert (d.donated_bytes > 0) == (not device_cache)
 
 
 def test_compiles_counted_on_a_new_shape_only(engine):

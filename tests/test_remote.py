@@ -325,6 +325,31 @@ def test_every_fast_operator_scores_bit_identically(sessions):
     assert (member.transfer_stats() - f0).wait_s > 0
 
 
+def test_staging_counters_cross_the_wire(world, sessions, monkeypatch):
+    """The worker engine counts a staging reuse or allocation for every
+    batch it loads from its store, and the counts reach the client's
+    flush counters through the protocol."""
+    ds, worker, _ = world
+    _, local, remote = sessions
+    member, = remote_members(remote.backend)
+    store = worker.engine.store
+    loads, inner = [], store.load_batch
+
+    def load_batch(*a, **kw):
+        loads.append(1)
+        return inner(*a, **kw)
+
+    monkeypatch.setattr(store, "load_batch", load_batch)
+    worker.engine.evict()                      # every flush loads
+    op = SemFilter("f1", 1)
+    f0 = member.transfer_stats()
+    for cand in local.backend.candidates(op):
+        remote.backend.score_filter(op, cand.name, ds.items[:32])
+    d = member.transfer_stats() - f0
+    assert loads
+    assert d.staged_reuses + d.staged_allocs == len(loads)
+
+
 @pytest.mark.parametrize("dispatcher", ["inline", "threads:2"])
 def test_same_plan_parity_local_vs_remote(sessions, dispatcher):
     """THE parity pin: one plan, two pools (one wired through the
