@@ -341,6 +341,8 @@ class ExplainReport:
                     f"wait_s={f.wait_s:.3f} prefetch_s={f.prefetch_s:.3f} "
                     f"compiles={f.compiles} compile_s={f.compile_s:.3f} "
                     f"padded_MB={f.padded_bytes / 1e6:.1f} "
+                    f"lru_hit_MB={f.lru_hit_bytes / 1e6:.1f} "
+                    f"lru_evictions={f.lru_evictions} "
                     f"donated_MB={f.donated_bytes / 1e6:.1f} "
                     f"staged_reuses={f.staged_reuses} "
                     f"staged_allocs={f.staged_allocs}")

@@ -114,6 +114,11 @@ class FlushCounters:
     donated_bytes: int = 0     # consumed cache buffers donated to XLA
     staged_reuses: int = 0     # batch loads the staging buffers had room for
     staged_allocs: int = 0     # batch loads that allocated or grew one
+    lru_hit_bytes: int = 0     # bytes of the batches served from the
+    #                            device LRU (stretto.engine.lookup hit=1):
+    #                            the hot counterpart of padded_bytes
+    lru_evictions: int = 0     # device-LRU entries this thread's loads
+    #                            pushed out to stay within the budget
 
     def __add__(self, other: "FlushCounters") -> "FlushCounters":
         return FlushCounters(**{f.name: getattr(self, f.name)

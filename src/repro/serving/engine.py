@@ -36,10 +36,11 @@ batch's caches can reuse that HBM instead of peaking at 2x; donated
 bytes are counted into `donated_bytes`.
 
 Telemetry: every flush runs inside `stretto.engine.*` spans (flush, load,
-dispatch, prefetch, wait; `CacheStore.load_batch` adds read, assemble and
-h2d) and adds to the calling thread's `FlushCounters`
+lookup, dispatch, prefetch, wait; `CacheStore.load_batch` adds read,
+assemble and h2d) and adds to the calling thread's `FlushCounters`
 (`transfer_stats_local`): read, assemble, host-to-device and wait
-seconds, prefetch seconds, XLA compiles, padded and donated bytes. The
+seconds, prefetch seconds, XLA compiles, padded and donated bytes, the
+bytes served from the device LRU and the entries its loads evicted. The
 counters are per thread, so the runtime's per-flush StageStats deltas
 stay exact under concurrent dispatch.
 
@@ -434,7 +435,10 @@ class ServingEngine:
                      ids: Sequence[int], headroom: int, n_real: int):
         """load_batch through the device-resident LRU (kv_bytes counts
         real loads only — a hit skips the npz-reload + re-pad + H2D copy
-        entirely), in the span `stretto.engine.load`."""
+        entirely), in the span `stretto.engine.load`. The LRU lookup runs
+        in `stretto.engine.lookup` (argument `hit` 1 or 0; a miss's load
+        follows it); a hit adds the batch's bytes to `lru_hit_bytes`, and
+        each entry a miss pushes out counts in `lru_evictions`."""
         with obs.span("stretto.engine.load", tuples=n_real):
             return self._lookup_or_load(em, profile, ids, headroom, n_real)
 
@@ -450,17 +454,21 @@ class ServingEngine:
         dev = None if placement is None else getattr(placement[0], "id",
                                                      placement[0])
         key = (profile.tag, tuple(ids), headroom, dev)
+        counters = self._counters()
         with self._dev_lock:
             hit = self._dev_cache.get(key)
-            if hit is not None:
-                self._dev_cache.move_to_end(key)
-                self.dev_cache_hits += 1
-                return hit[0]
+            with obs.span("stretto.engine.lookup", hit=int(hit is not None)):
+                if hit is not None:
+                    self._dev_cache.move_to_end(key)
+                    self.dev_cache_hits += 1
+                    counters.lru_hit_bytes += sum(
+                        int(v.nbytes) for k, v in hit[0].items()
+                        if k != "lengths")
+                    return hit[0]
             self.dev_cache_misses += 1
             cache, _ = self.store.load_batch(
                 em.cfg, profile, ids, pad_to_multiple=KERNEL_BLOCK_S,
-                headroom=headroom, n_real=n_real,
-                counters=self._counters())
+                headroom=headroom, n_real=n_real, counters=counters)
             nbytes = sum(np.asarray(v).nbytes if not hasattr(v, "nbytes")
                          else v.nbytes for v in cache.values())
             self._dev_cache[key] = (cache, nbytes)
@@ -469,6 +477,7 @@ class ServingEngine:
                     and len(self._dev_cache) > 1:
                 _, (_, old_bytes) = self._dev_cache.popitem(last=False)
                 self._dev_bytes -= old_bytes
+                counters.lru_evictions += 1
             return cache
 
     def _load_for(self, em: EngineModel, profile: Profile, ids: List[int],

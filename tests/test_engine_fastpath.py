@@ -254,6 +254,94 @@ def test_device_cache_evicts_lru_under_budget(engine):
         eng.memory_budget = budget0
 
 
+def test_lru_hit_bytes_count_a_repeated_batch(engine):
+    """A repeated batch is served from the device LRU and counts, in
+    `lru_hit_bytes`, the bytes of the padded batch its first flush loaded
+    and stored (`padded_bytes` of that miss); nothing is evicted."""
+    eng, ds = engine
+    ids = _ids(ds, 8)
+    query = [filter_query_token(2)]
+    try:
+        eng.device_cache = True
+        eng.device_cache_clear()
+        c0 = eng.transfer_stats_local()
+        eng.run_filter("sm", 0.0, ids, query, TOK_YES, TOK_NO)
+        miss = eng.transfer_stats_local() - c0
+        (stored, _), = eng._dev_cache.values()
+        eng.run_filter("sm", 0.0, ids, query, TOK_YES, TOK_NO)
+        hit = eng.transfer_stats_local() - c0 - miss
+    finally:
+        eng.device_cache = False
+        eng.device_cache_clear()
+    assert miss.lru_hit_bytes == 0 and miss.padded_bytes > 0
+    assert hit.lru_hit_bytes == miss.padded_bytes == sum(
+        int(v.nbytes) for k, v in stored.items() if k != "lengths")
+    assert hit.padded_bytes == 0
+    assert miss.lru_evictions == hit.lru_evictions == 0
+
+
+def test_lru_evictions_counted_under_a_small_budget(engine):
+    """With a budget that holds one batch and not two, every load after
+    the first pushes the oldest entry out and counts it."""
+    eng, ds = engine
+    query = [filter_query_token(1)]
+    batches = [_ids(ds, 12)[4 * s:4 * s + 4] for s in range(3)]
+    budget0 = eng.memory_budget
+    try:
+        eng.device_cache = True
+        eng.device_cache_clear()
+        eng.run_filter("sm", 0.0, batches[0], query, TOK_YES, TOK_NO)
+        eng.memory_budget = 1.5 * eng._dev_bytes
+        eng.device_cache_clear()
+        c0 = eng.transfer_stats_local()
+        for ids in batches:
+            eng.run_filter("sm", 0.0, ids, query, TOK_YES, TOK_NO)
+        d = eng.transfer_stats_local() - c0
+        assert len(eng._dev_cache) == 1
+    finally:
+        eng.device_cache = False
+        eng.device_cache_clear()
+        eng.memory_budget = budget0
+    assert d.lru_evictions == 2
+    assert d.lru_hit_bytes == 0
+
+
+def test_lru_lookup_span_says_hit_or_miss(engine, tmp_path):
+    """Each LRU lookup opens `stretto.engine.lookup` inside
+    `stretto.engine.load`, with `hit` 0 for a miss and 1 for a hit."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    eng, ds = engine
+    ids = _ids(ds, 4)
+    try:
+        eng.device_cache = True
+        eng.device_cache_clear()
+        with jax.profiler.trace(str(tmp_path)):
+            for _ in range(2):
+                eng.run_filter("sm", 0.0, ids, [filter_query_token(3)],
+                               TOK_YES, TOK_NO)
+    finally:
+        eng.device_cache = False
+        eng.device_cache_clear()
+    spans = {"stretto.engine.load": [], "stretto.engine.lookup": []}
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in spans:
+                    spans[ev.name].append(
+                        (line.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                         dict(ev.stats)))
+    lookups = sorted(spans["stretto.engine.lookup"], key=lambda e: e[1])
+    assert [int(e[3]["hit"]) for e in lookups] == [0, 1]
+    for ln, s, e, _ in lookups:
+        assert any(ln == la and sa <= s and e <= ea
+                   for la, sa, ea, _ in spans["stretto.engine.load"])
+
+
 # ---------------------------------------------------------------------------
 # int8 KV profiles end to end
 # ---------------------------------------------------------------------------
@@ -375,6 +463,34 @@ def test_explain_analyze_prints_staging_counters(api_world):
     allocs = sum(f.staged_allocs for f in flush)
     assert reuses + allocs > 0
     assert line.endswith(f" staged_reuses={reuses} staged_allocs={allocs}")
+
+
+def test_explain_analyze_prints_lru_counters(api_world):
+    """EXPLAIN ANALYZE's flush line carries the device-LRU counters summed
+    over the run's stage flushes: a second run of the same query is
+    served from the LRU and evicts nothing."""
+    import re
+
+    ds, sess = api_world
+    engines = list(sess.engines.values())
+    flags = [eng.device_cache for eng in engines]
+    try:
+        for eng in engines:
+            eng.device_cache = True
+            eng.device_cache_clear()
+        frame = sess.frame(ds.items).sem_filter("f1", 1)
+        frame.execute()
+        res = frame.execute()
+    finally:
+        for eng, flag in zip(engines, flags):
+            eng.device_cache = flag
+            eng.device_cache_clear()
+    flush = [s.flush for s in res.stage_stats]
+    hit_bytes = sum(f.lru_hit_bytes for f in flush)
+    assert hit_bytes > 0 and sum(f.padded_bytes for f in flush) == 0
+    line, = re.findall(r"^flush: .*$", res.explain_analyze().render(),
+                       flags=re.M)
+    assert f" lru_hit_MB={hit_bytes / 1e6:.1f} lru_evictions=0 " in line
 
 
 def test_session_config_validates_kernels_backend():

@@ -88,6 +88,8 @@ PARITY_CASES = [
     (2, 256, 2, 2, 64, 32, GLOBAL),   # dv != dk
     (2, 256, 2, 4, 64, 64, 64),       # sliding window
     (4, 128, 2, 2, 32, 32, 17),       # window not a block multiple
+    (3, 256, 2, 7, 64, 64, GLOBAL),   # G = 7 (Yi-34B): rows not a power of 2
+    (2, 384, 1, 7, 128, 128, 100),    # G = 7, d_head 128, sliding window
 ]
 
 
@@ -144,6 +146,8 @@ QUERY_CASES = [
     (2, 256, 2, 2, 64, 32, 6, GLOBAL),  # dv != dk
     (2, 256, 2, 4, 64, 64, 6, 64),      # sliding window
     (1, 128, 2, 2, 32, 32, 1, GLOBAL),  # Lq=1 degenerate
+    (3, 256, 2, 7, 64, 64, 6, GLOBAL),  # G = 7 (Yi-34B), ragged lengths
+    (4, 384, 1, 7, 128, 128, 1, GLOBAL),  # G = 7, Lq=1: 7 query rows
 ]
 
 

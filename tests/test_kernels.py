@@ -25,6 +25,8 @@ DECODE_CASES = [
     (2, 2, 2, 64, 32, 256, 128, 1 << 30, jnp.float32),   # dv != dk (MLA)
     (2, 2, 4, 64, 64, 256, 128, 64, jnp.float32),        # windowed
     (1, 1, 4, 256, 128, 512, 128, 1 << 30, jnp.float32),  # latent-wide
+    (3, 2, 7, 128, 128, 384, 128, 1 << 30, jnp.float32),  # G = 7 (Yi-34B)
+    (4, 1, 7, 128, 128, 256, 128, 1 << 30, jnp.bfloat16),  # G = 7, bf16
 ]
 
 

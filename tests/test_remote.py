@@ -350,6 +350,32 @@ def test_staging_counters_cross_the_wire(world, sessions, monkeypatch):
     assert d.staged_reuses + d.staged_allocs == len(loads)
 
 
+def test_lru_counters_cross_the_wire(world, sessions):
+    """A batch the worker engine's device LRU already holds is served from
+    it: the bytes it served reach the client's flush counters through the
+    protocol, and nothing was evicted."""
+    ds, worker, _ = world
+    _, local, remote = sessions
+    member, = remote_members(remote.backend)
+    eng = worker.engine
+    op = SemFilter("f1", 1)
+    cand = next(c for c in local.backend.candidates(op)
+                if c.name.startswith("fast/") and "-kv" in c.name)
+    flag = eng.device_cache
+    try:
+        eng.device_cache = True
+        eng.evict()
+        remote.backend.score_filter(op, cand.name, ds.items[:16])
+        f0 = member.transfer_stats()
+        remote.backend.score_filter(op, cand.name, ds.items[:16])
+        d = member.transfer_stats() - f0
+    finally:
+        eng.device_cache = flag
+        eng.evict()
+    assert d.lru_hit_bytes > 0 and d.padded_bytes == 0
+    assert d.lru_evictions == 0
+
+
 @pytest.mark.parametrize("dispatcher", ["inline", "threads:2"])
 def test_same_plan_parity_local_vs_remote(sessions, dispatcher):
     """THE parity pin: one plan, two pools (one wired through the

@@ -78,11 +78,14 @@ def test_split_counters_tiles_exactly():
     total = FlushCounters(read_s=0.3, assemble_s=1.2, h2d_s=0.9, wait_s=2.1,
                           prefetch_s=0.6, compile_s=0.5, compiles=3,
                           padded_bytes=1003, donated_bytes=97,
-                          staged_reuses=5, staged_allocs=2)
+                          staged_reuses=5, staged_allocs=2,
+                          lru_hit_bytes=10_007, lru_evictions=3)
     sizes = [37, 1, 0, 256]
     parts = split_counters(total, sizes)
     assert [p.padded_bytes for p in parts] == split_ints(1003, sizes)
     assert [p.staged_reuses for p in parts] == split_ints(5, sizes)
+    assert [p.lru_hit_bytes for p in parts] == split_ints(10_007, sizes)
+    assert [p.lru_evictions for p in parts] == split_ints(3, sizes)
     assert sum(p.compiles for p in parts) == 3
     summed = sum(parts, FlushCounters())
     for name, value in total.as_dict().items():

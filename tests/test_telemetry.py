@@ -111,7 +111,8 @@ class _CountingBackend(OracleBackend):
     PER_TUPLE = FlushCounters(read_s=1e-4, assemble_s=3e-3, h2d_s=2e-3,
                               wait_s=5e-3, prefetch_s=1e-3, compile_s=7e-4,
                               compiles=1, padded_bytes=96, donated_bytes=64,
-                              staged_reuses=1, staged_allocs=2)
+                              staged_reuses=1, staged_allocs=2,
+                              lru_hit_bytes=48, lru_evictions=1)
 
     def __init__(self, registry):
         super().__init__(registry)

@@ -3,9 +3,11 @@
 Interpret mode runs a kernel's math on the CPU but never applies the TPU
 compiler's tiling and VMEM rules. These tests lower and compile the
 flash-decode, fused-query and Expected-Attention kernels for one chip of a
-described (not attached) `v5e:2x2` topology, at the planted models' widths
-and at Llama-3.1-8B's published widths (d_head 128, 8 KV heads, 4 query
-heads per KV head), with float32, bf16 and int8 caches. A refused block
+described (not attached) `v5e:2x2` topology, at the planted models' widths,
+at Llama-3.1-8B's published widths (d_head 128, 8 KV heads, 4 query heads
+per KV head) and at Yi-34B's (d_head 128, 8 KV heads, 7 query heads per
+KV head: query rows not a power of two), with float32, bf16 and int8
+caches. A refused block
 shape or an over-budget kernel fails here, in about two seconds a case,
 instead of on the chip. Nothing runs, so no result is checked.
 
@@ -19,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.configs.llava_next_34b import CONFIG as YI_34B
 from repro.configs.stretto_llama_8b import CONFIG as LLAMA_8B
 from repro.data.synthetic import planted_config
 from repro.kernels.decode_attention import (decode_attention,
@@ -30,7 +33,8 @@ B, S, LQ = 8, 512, 6
 WIDTHS = {name: (c.n_kv_heads, c.n_heads // c.n_kv_heads, c.d_head)
           for name, c in (("planted-sm", planted_config("sm")),
                           ("planted-lg", planted_config("lg")),
-                          ("llama-8b", LLAMA_8B))}
+                          ("llama-8b", LLAMA_8B),
+                          ("yi-34b", YI_34B))}   # LLaVA-NeXT-34B's LM
 
 
 @pytest.fixture(scope="module")
