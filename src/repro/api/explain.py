@@ -338,9 +338,11 @@ class ExplainReport:
                 out.append(
                     f"flush: read_s={f.read_s:.3f} "
                     f"assemble_s={f.assemble_s:.3f} h2d_s={f.h2d_s:.3f} "
+                    f"layout_s={f.layout_s:.3f} "
                     f"wait_s={f.wait_s:.3f} prefetch_s={f.prefetch_s:.3f} "
                     f"compiles={f.compiles} compile_s={f.compile_s:.3f} "
                     f"padded_MB={f.padded_bytes / 1e6:.1f} "
+                    f"h2d_MB={f.h2d_bytes / 1e6:.1f} "
                     f"lru_hit_MB={f.lru_hit_bytes / 1e6:.1f} "
                     f"lru_evictions={f.lru_evictions} "
                     f"donated_MB={f.donated_bytes / 1e6:.1f} "

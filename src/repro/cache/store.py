@@ -5,9 +5,10 @@ quantization). The store holds one compressed cache per (profile, item)
 as an .npz shard, written once in the offline phase and memory-mapped at
 query time. `load_batch` re-pads a set of items to the max compressed
 length in the batch — the paper's batching scheme — and returns a
-decode-ready cache pytree. It copies each item once, into its slot of a
+decode-ready cache pytree. It copies each item once, back to back into a
 host staging buffer that the calling thread reuses from batch to batch
-(`_Staging`), and hands that buffer to the device.
+(`_Staging`), sends that buffer to the device unpadded, and pads it there
+(`_unflatten`).
 
 Alongside the shards, each profile directory carries an append-only
 `_meta.jsonl` recording per-item byte sizes at `save` time, so batch
@@ -16,6 +17,7 @@ decompressing a full .npz shard per flush.
 """
 from __future__ import annotations
 
+import functools
 import json
 import math
 import os
@@ -24,16 +26,19 @@ import weakref
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro import obs
 from repro.configs.base import ModelConfig
 from repro.obs import FlushCounters
 
 META_FILE = "_meta.jsonl"
-# cache keys laid out (L, S', ...) per item: padded along S' to the batch's
-# S_max; every other key is stacked as it is
+# cache keys laid out (L, S', ...) per item: sent back to back along S' and
+# padded on the device to the batch's S_max; every other key is stacked
+# as it is
 SEQ_KEYS = frozenset({"k", "v", "c_kv", "k_rope", "k_scale", "v_scale"})
 
 
@@ -49,32 +54,56 @@ class Profile:
         return base + ("__q8" if self.quant else "")
 
 
+def _flat_len(n: int, multiple: int) -> int:
+    """`n` positions rounded up to a multiple of `multiple`, and from 32
+    such multiples on to one of 16 lengths per octave (less than n/16
+    more), so that few distinct flat buffer shapes, and so few layout
+    programs, occur."""
+    step = max(multiple, 1 << max(0, n.bit_length() - 5))
+    return -(-n // step) * step
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _unflatten(flat, spans, smax: int):
+    """The padded batch from items laid back to back: (L, T, ...) ->
+    (L, B, smax, ...), where item b is `spans[1, b]` positions of `flat`
+    from `spans[0, b]` and every later position is exactly 0 (V's pads are
+    multiplied by softmax weights of 0, so they must not be NaN). Every
+    slice fits: T >= max(spans[0]) + smax."""
+    rows = jax.vmap(lambda o: lax.dynamic_slice_in_dim(flat, o, smax, 1),
+                    out_axes=1)(spans[0])
+    keep = jnp.arange(smax)[None, :] < spans[1][:, None]
+    keep = keep.reshape((1,) + keep.shape + (1,) * (flat.ndim - 2))
+    return jnp.where(keep, rows, jnp.zeros((), flat.dtype))
+
+
 class _Staging:
-    """One reused host buffer that a batch of one cache key is assembled
-    in: flat bytes, viewed as the C-contiguous (L, B, S_max, ...) batch.
+    """One reused host buffer that one cache key of a batch is assembled
+    in: flat bytes, viewed as the C-contiguous (L, T, ...) items laid back
+    to back along axis 1 (sequence keys), or as the (L, B, ...) items
+    stacked on axis 1 (any other key).
 
     It grows to the largest batch it has held and never shrinks. Each item
-    is copied once into its slot through a same-width unsigned-integer
-    view, which numpy copies as plain memory whatever the dtype (bfloat16,
-    int8, float32). Pad positions read 0: while the batch's shape is
-    unchanged only `[n_i, high_i)` of slot i is zeroed, the part a longer
-    earlier item left; any other shape zeroes the whole pad.
+    is copied once into place through a same-width unsigned-integer view,
+    which numpy copies as plain memory whatever the dtype (bfloat16, int8,
+    float32). Nothing else is written: whatever lies past the items of a
+    flat buffer reaches the batch only as the zeros `_unflatten` puts in
+    its place.
 
-    `sent` is a weak reference to the device array last copied from the
+    `sent` is a weak reference to the device array last made from the
     buffer (weak, so that the buffer holds no device memory): an
-    asynchronous host-to-device copy reads the buffer until it lands, so
-    `wait` blocks on it before the buffer is written again. An
-    array that was donated to a computation is skipped: the caller forced
-    that computation, and so the copy, before loading into this buffer
-    again (the engine does, within two loads).
+    asynchronous host-to-device copy reads the buffer until it lands, and
+    that array is ready only after it has, so `wait` blocks on it before
+    the buffer is written again. An array that was donated to a
+    computation is skipped: the caller forced that computation, and so the
+    copy, before loading into this buffer again (the engine does, within
+    two loads).
     """
 
-    __slots__ = ("buf", "layout", "high", "sent")
+    __slots__ = ("buf", "sent")
 
     def __init__(self):
         self.buf = np.empty(0, np.uint8)
-        self.layout: Optional[Tuple] = None
-        self.high: List[int] = []
         self.sent: Optional[weakref.ref] = None
 
     def wait(self) -> None:
@@ -83,35 +112,27 @@ class _Staging:
             arr.block_until_ready()
 
     def fill(self, items: Sequence[np.ndarray],
-             smax: Optional[int]) -> Tuple[np.ndarray, bool]:
-        """The batch of `items`, padded along axis 1 to `smax` (None:
-        stacked unpadded), and whether the buffer had to grow."""
+             offsets: Optional[Sequence[int]] = None,
+             flat_len: int = 0) -> Tuple[np.ndarray, bool]:
+        """`items` stacked on a new axis 1 (`offsets` None), or item i at
+        `offsets[i]` along axis 1 of a (L, flat_len, ...) buffer; and
+        whether the buffer had to grow."""
         a0 = items[0]
-        shape = (a0.shape[0], len(items)) + (
-            a0.shape[1:] if smax is None else (smax,) + a0.shape[2:])
+        shape = (a0.shape[0],) + ((len(items),) + a0.shape[1:]
+                                  if offsets is None
+                                  else (flat_len,) + a0.shape[2:])
         nbytes = math.prod(shape) * a0.dtype.itemsize
         grew = nbytes > self.buf.nbytes
         if grew:
             self.buf = np.empty(nbytes, np.uint8)
-        layout = (shape, a0.dtype)
-        if grew or layout != self.layout:
-            self.high = [shape[2] if smax is not None else 0] * len(items)
-        # unset until every slot is written, so a fill that raises leaves
-        # the next one to zero the whole pad
-        self.layout = None
         out = self.buf[:nbytes].view(a0.dtype).reshape(shape)
         raw = out.view(f"u{a0.dtype.itemsize}")
         for i, a in enumerate(items):
             src = a.view(raw.dtype)
-            if smax is None:
+            if offsets is None:
                 raw[:, i] = src
-                continue
-            n = a.shape[1]
-            raw[:, i, :n] = src
-            if self.high[i] > n:
-                raw[:, i, n:self.high[i]] = 0
-            self.high[i] = n
-        self.layout = layout
+            else:
+                raw[:, offsets[i]:offsets[i] + a.shape[1]] = src
         return out, grew
 
 
@@ -261,19 +282,28 @@ class CacheStore:
 
         Each cache key's batch is assembled in one of two host staging
         buffers per key that the calling thread uses in turn (`_Staging`):
-        each item is copied once, into its slot. So the copy to the device
-        of one batch may still be in flight while the next is assembled;
-        before a buffer is written again, the copy made from it last has
-        to land. The returned arrays never alias a buffer.
+        each distinct item is copied once. So the copy to the device of one
+        batch may still be in flight while the next is assembled; before a
+        buffer is written again, the array made from it last has to be
+        ready. The returned arrays never alias a buffer.
+
+        A sequence key (`SEQ_KEYS`) crosses to the device unpadded: its
+        distinct items lie back to back in a (L, T, ...) buffer, the
+        longest last, T rounded up by `_flat_len` from the end of that
+        item's slice of S_max; `_unflatten` pads them into the batch on
+        the flat array's device (an item repeated to round the batch up is
+        sent once). Any other key (recurrent state) is stacked on the host.
 
         The steps run in the spans `stretto.store.read`, then
-        `stretto.store.h2d` (waiting for the copies out of this turn's
-        buffers to land), then per cache key `stretto.store.assemble`
-        (filling its buffer) and `stretto.store.h2d` (the copy to the
-        device); with `counters` given, their seconds, the bytes of the
-        padded batch and whether the buffers had room for it
-        (`staged_reuses`) or one had to grow (`staged_allocs`) are added
-        to it.
+        `stretto.store.h2d` (waiting for the arrays last made from this
+        turn's buffers), then per cache key `stretto.store.assemble`
+        (filling its buffer), `stretto.store.h2d` (the copy to the device)
+        and, for a sequence key, `stretto.store.layout` (the dispatch of
+        `_unflatten`, with its compile on a new shape); with `counters`
+        given, their seconds, the bytes of the padded batch
+        (`padded_bytes`), the bytes sent to the device (`h2d_bytes`) and
+        whether the buffers had room for the batch (`staged_reuses`) or
+        one had to grow (`staged_allocs`) are added to it.
         """
         with obs.span("stretto.store.read") as read:
             shards = [self.load(profile, i) for i in item_ids]
@@ -287,6 +317,16 @@ class CacheStore:
         smax = int(lengths.max()) + headroom
         smax = ((smax + pad_to_multiple - 1) // pad_to_multiple
                 * pad_to_multiple)
+        # each distinct item once, in order of first appearance; slot[b]
+        # is batch row b's item among them
+        first: Dict[int, int] = {}
+        uniq: List[Dict[str, np.ndarray]] = []
+        slot: List[int] = []
+        for i, s in zip(item_ids, shards):
+            if i not in first:
+                first[i] = len(uniq)
+                uniq.append(s)
+            slot.append(first[i])
         keys = [k for k in shards[0] if k != "__length__"]
         turn = getattr(self._tl, "turn", 0)
         self._tl.turn = 1 - turn
@@ -297,29 +337,66 @@ class CacheStore:
             for buf in bufs:
                 buf.wait()
         cache: Dict[str, Any] = {}
-        assemble_s, h2d_s, grew = 0.0, landed.secs, False
+        # per tuple of item lengths: (item offsets, flat length, spans on
+        # the device)
+        flats: Dict[Tuple[int, ...], Tuple[List[int], int, Any]] = {}
+        assemble_s, h2d_s, layout_s = 0.0, landed.secs, 0.0
+        grew, sent = False, 0
         for key, buf in zip(keys, bufs):
-            with obs.span("stretto.store.assemble", key=key) as assemble:
-                batch, g = buf.fill([s[key] for s in shards],
-                                    smax if key in SEQ_KEYS else None)
-            with obs.span("stretto.store.h2d", key=key) as h2d:
-                cache[key] = jnp.asarray(batch)
-                if cache[key].device.platform == "cpu":
-                    # the CPU adopts an aligned host buffer as the array's
-                    # own memory: give it one that is never refilled
-                    cache[key] = jnp.asarray(batch.copy())
+            if key in SEQ_KEYS:
+                ns = tuple(s[key].shape[1] for s in uniq)
+                if ns not in flats:
+                    # the longest item last: only its slice ends past the
+                    # items, by S_max less its length
+                    last = ns.index(max(ns))
+                    offs, end = [0] * len(ns), 0
+                    for j in [j for j in range(len(ns)) if j != last] + [last]:
+                        offs[j], end = end, end + ns[j]
+                    flat_len = _flat_len(offs[last] + smax, pad_to_multiple)
+                    # `_unflatten` slices S_max from every offset, and
+                    # dynamic_slice would clamp one that ran past T
+                    assert ns[last] <= smax <= flat_len - offs[last], \
+                        (ns, smax, flat_len)
+                    spans = np.array([[offs[j] for j in slot],
+                                      [ns[j] for j in slot]], np.int32)
+                    with obs.span("stretto.store.h2d", key="spans") as h2d:
+                        flats[ns] = (offs, flat_len, jnp.asarray(spans))
+                    h2d_s += h2d.secs
+                    sent += spans.nbytes
+                offs, flat_len, spans_dev = flats[ns]
+                with obs.span("stretto.store.assemble", key=key) as assemble:
+                    host, g = buf.fill([s[key] for s in uniq], offs, flat_len)
+                with obs.span("stretto.store.h2d", key=key) as h2d:
+                    flat = jnp.asarray(host)
+                with obs.span("stretto.store.layout", key=key) as layout:
+                    cache[key] = _unflatten(flat, spans_dev, smax)
+                layout_s += layout.secs
+                del flat    # the batch is all the device keeps
+            else:
+                with obs.span("stretto.store.assemble", key=key) as assemble:
+                    host, g = buf.fill([s[key] for s in shards])
+                with obs.span("stretto.store.h2d", key=key) as h2d:
+                    cache[key] = jnp.asarray(host)
+                    if cache[key].device.platform == "cpu":
+                        # the CPU adopts an aligned host buffer as the
+                        # array's own memory: give it one that is never
+                        # refilled
+                        cache[key] = jnp.asarray(host.copy())
             buf.sent = weakref.ref(cache[key])
             assemble_s += assemble.secs
             h2d_s += h2d.secs
             grew |= g
+            sent += host.nbytes
         with obs.span("stretto.store.h2d", key="lengths") as h2d:
             cache["lengths"] = jnp.asarray(lengths)
         if counters is not None:
             counters.read_s += read.secs
             counters.assemble_s += assemble_s
             counters.h2d_s += h2d_s + h2d.secs
+            counters.layout_s += layout_s
             counters.padded_bytes += sum(int(v.nbytes) for k, v in
                                          cache.items() if k != "lengths")
+            counters.h2d_bytes += sent + lengths.nbytes
             counters.staged_allocs += int(grew)
             counters.staged_reuses += int(not grew)
         return cache, lengths

@@ -236,7 +236,10 @@ def profile_query(query: Query, items: Sequence[Any],
     # cost-curve points: two sub-sample batch sizes, each timed on a
     # *second* (warmed) call so jit compilation lands in neither point —
     # the full-sample scoring run stays cold (its compile would otherwise
-    # masquerade as per-tuple cost in the fit)
+    # masquerade as per-tuple cost in the fit). Its price leaves out the
+    # programs that pad a cache batch on the device (`layout_s`): they
+    # compile per flat length, which follows the sample's item lengths,
+    # not the operator
     b_small = max(2, k // 8) if k >= 9 else 0
     b_mid = max(b_small + 1, k // 3) if b_small else 0
 
@@ -248,7 +251,8 @@ def profile_query(query: Query, items: Sequence[Any],
         for phys in ops:
             out = run_operator(backend, op, phys.name, sample)
             scores.append(np.asarray(out.scores, np.float32))
-            costs.append(max(out.wall_s / max(len(sample), 1), 1e-9))
+            costs.append(max((out.wall_s - out.flush.layout_s)
+                             / max(len(sample), 1), 1e-9))
             if out.values is not None:
                 values.append(np.asarray(out.values))
             points = []

@@ -94,23 +94,30 @@ class FlushCounters:
     """Where a flush's host time went, and what it moved. Times are host
     seconds of the matching `stretto.*` spans; counts are exact."""
     read_s: float = 0.0        # shard reads (stretto.store.read)
-    assemble_s: float = 0.0    # copying each item into its slot of the
-    #                            reused staging buffers, zeroing the pad
+    assemble_s: float = 0.0    # copying each item into place in the
+    #                            reused staging buffers
     #                            (stretto.store.assemble)
     h2d_s: float = 0.0         # jnp.asarray copies of the batch to the
     #                            device, and waiting for the last copy out
     #                            of a staging buffer to land before it is
     #                            refilled (stretto.store.h2d)
+    layout_s: float = 0.0      # dispatching the device program that pads
+    #                            a sequence key's flat buffer into the
+    #                            batch, its compile on a new flat length
+    #                            included, which compile_s counts too
+    #                            (stretto.store.layout)
     wait_s: float = 0.0        # blocked forcing a flush's result to the
     #                            host (stretto.engine.wait)
     prefetch_s: float = 0.0    # the part of read_s + assemble_s + h2d_s
-    #                            spent on batch i+1 before batch i's result
-    #                            was forced: an upper bound on the time the
-    #                            device hid; only a device trace shows the
-    #                            time actually hidden
+    #                            + layout_s spent on batch i+1 before batch
+    #                            i's result was forced: an upper bound on
+    #                            the time the device hid; only a device
+    #                            trace shows the time actually hidden
     compile_s: float = 0.0     # XLA compiles on the flush's thread
     compiles: int = 0
     padded_bytes: int = 0      # bytes of the padded batches built
+    h2d_bytes: int = 0         # bytes sent to the device to build them:
+    #                            sequence keys unpadded, back to back
     donated_bytes: int = 0     # consumed cache buffers donated to XLA
     staged_reuses: int = 0     # batch loads the staging buffers had room for
     staged_allocs: int = 0     # batch loads that allocated or grew one

@@ -37,12 +37,13 @@ bytes are counted into `donated_bytes`.
 
 Telemetry: every flush runs inside `stretto.engine.*` spans (flush, load,
 lookup, dispatch, prefetch, wait; `CacheStore.load_batch` adds read,
-assemble and h2d) and adds to the calling thread's `FlushCounters`
-(`transfer_stats_local`): read, assemble, host-to-device and wait
-seconds, prefetch seconds, XLA compiles, padded and donated bytes, the
-bytes served from the device LRU and the entries its loads evicted. The
-counters are per thread, so the runtime's per-flush StageStats deltas
-stay exact under concurrent dispatch.
+assemble, h2d and layout) and adds to the calling thread's
+`FlushCounters` (`transfer_stats_local`): read, assemble,
+host-to-device, layout and wait seconds, prefetch seconds, XLA compiles,
+padded, sent and donated bytes, the bytes served from the device LRU and
+the entries its loads evicted. The counters are per thread, so the
+runtime's per-flush StageStats deltas stay exact under concurrent
+dispatch.
 
 Multi-device placement: `place_on(device)` pins the calling thread's
 flushes — params (device_put once per device, memoized) and decode
@@ -562,7 +563,8 @@ class ServingEngine:
         and before its logits are forced, in the span
         `stretto.engine.prefetch`: the consumer's np.asarray then blocks
         on the decode while the transfer proceeds. The load's read,
-        assemble and h2d seconds are also counted into `prefetch_s`."""
+        assemble, h2d and layout seconds are also counted into
+        `prefetch_s`."""
         batches = [(s, list(item_ids[s:s + bs]))
                    for s in range(0, len(item_ids), bs)]
         prefetch = self.async_h2d and len(batches) > 1
@@ -574,11 +576,12 @@ class ServingEngine:
             if prefetch and bi + 1 < len(batches):
                 nxt = batches[bi + 1][1]
                 c = self._counters()
-                before = c.read_s + c.assemble_s + c.h2d_s
+                before = c.read_s + c.assemble_s + c.h2d_s + c.layout_s
                 with obs.span("stretto.engine.prefetch"), \
                         self._device_ctx(self._placement()):
                     pre = self._load_for(em, profile, nxt, query_tokens, bs)
-                c.prefetch_s += c.read_s + c.assemble_s + c.h2d_s - before
+                c.prefetch_s += (c.read_s + c.assemble_s + c.h2d_s
+                                 + c.layout_s - before)
             yield s, ids, logits
     def run_filter(self, model_name: str, profile_ratio: float,
                    item_ids: Sequence[int], query_tokens: Sequence[int],

@@ -465,6 +465,49 @@ def test_explain_analyze_prints_staging_counters(api_world):
     assert line.endswith(f" staged_reuses={reuses} staged_allocs={allocs}")
 
 
+def test_explain_analyze_prints_h2d_bytes(api_world, monkeypatch):
+    """EXPLAIN ANALYZE's flush line carries `h2d_MB`: the bytes that
+    crossed to the device, summed over the run's stage flushes. They are
+    exactly the flat buffers each load sent for its layout on the device,
+    their item spans and the lengths vectors, under the padded batches'
+    bytes."""
+    import re
+
+    from repro.cache import store as store_mod
+
+    ds, sess = api_world
+    flats, spans_sent, lengths = [], {}, []
+    unflatten = store_mod._unflatten
+
+    def spy(flat, spans, smax):
+        flats.append(flat.nbytes)
+        spans_sent[id(spans)] = spans      # held, so ids stay unique
+        return unflatten(flat, spans, smax)
+
+    frame = sess.frame(ds.items).sem_filter("f1", 1)
+    frame.execute()                        # plans: its profiling loads
+    monkeypatch.setattr(store_mod, "_unflatten", spy)
+    for eng in sess.engines.values():
+        eng.device_cache_clear()
+        load_batch = eng.store.load_batch
+
+        def load_spy(*a, _inner=load_batch, **kw):
+            cache, lens = _inner(*a, **kw)
+            lengths.append(lens.nbytes)
+            return cache, lens
+
+        monkeypatch.setattr(eng.store, "load_batch", load_spy)
+    res = frame.execute()
+    flush = [s.flush for s in res.stage_stats]
+    h2d = sum(f.h2d_bytes for f in flush)
+    assert flats and h2d < sum(f.padded_bytes for f in flush)
+    assert h2d == (sum(flats) + sum(a.nbytes for a in spans_sent.values())
+                   + sum(lengths))
+    line, = re.findall(r"^flush: .*$", res.explain_analyze().render(),
+                       flags=re.M)
+    assert f" h2d_MB={h2d / 1e6:.1f} " in line
+
+
 def test_explain_analyze_prints_lru_counters(api_world):
     """EXPLAIN ANALYZE's flush line carries the device-LRU counters summed
     over the run's stage flushes: a second run of the same query is

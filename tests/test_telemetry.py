@@ -109,10 +109,11 @@ class _CountingBackend(OracleBackend):
     with the tuples each flush scores, as a serving engine's does."""
 
     PER_TUPLE = FlushCounters(read_s=1e-4, assemble_s=3e-3, h2d_s=2e-3,
-                              wait_s=5e-3, prefetch_s=1e-3, compile_s=7e-4,
-                              compiles=1, padded_bytes=96, donated_bytes=64,
-                              staged_reuses=1, staged_allocs=2,
-                              lru_hit_bytes=48, lru_evictions=1)
+                              layout_s=4e-4, wait_s=5e-3, prefetch_s=1e-3,
+                              compile_s=7e-4, compiles=1, padded_bytes=96,
+                              donated_bytes=64, staged_reuses=1,
+                              staged_allocs=2, lru_hit_bytes=48,
+                              lru_evictions=1)
 
     def __init__(self, registry):
         super().__init__(registry)
@@ -458,6 +459,43 @@ def test_batch_drift_ratio():
     # stages without a planned batch expectation are skipped
     assert batch_drift(plan, [StageStats("unknown", 9, 9, n_tuples=5,
                                          n_batches=5)]) == 1.0
+
+
+def test_cold_profiling_price_leaves_out_layout_programs():
+    """The planner prices an operator from its cold full-sample run less
+    the flush's `layout_s`: the device programs that pad a cache batch
+    compile per flat length, which follows the sample's item lengths and
+    not the operator, so the first operator to meet a length must not pay
+    for it. Whatever else the cold run spends stays in the price."""
+    from repro.core import profile_query
+
+    layout_s, other_s = 0.3, 0.05
+
+    class _LayoutBackend(OracleBackend):
+        def __init__(self, registry):
+            super().__init__(registry)
+            self.calls = 0
+
+        def transfer_stats(self):
+            return FlushCounters(layout_s=layout_s * self.calls)
+
+        def score_filter(self, op, op_name, items):
+            if op_name == "f-cheap":
+                self.calls += 1
+                time.sleep(layout_s)
+            else:
+                time.sleep(other_s)
+            return super().score_filter(op, op_name, items)
+
+    cheap, gold = _Filter("f-cheap", 1), _Filter("f-gold", 2, is_gold=True)
+    backend = _LayoutBackend(lambda op: [cheap, gold])
+    items = [_Item(i) for i in range(40)]
+    q = Query([SemFilter("f", 1)], target_recall=0.8, target_precision=0.8)
+    (prof,), sample = profile_query(q, items, backend, sample_frac=0.5)
+    n = len(sample)
+    assert prof.op_names == ["f-cheap", "f-gold"]
+    assert prof.costs[0] < 0.2 * layout_s / n
+    assert prof.costs[1] >= other_s / n
 
 
 def test_plan_prices_measured_widths(tmp_path):
